@@ -48,6 +48,7 @@ def main() -> None:
             if not want or any(w in m for w in want)]
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for name in mods:
         mod = importlib.import_module(f"benchmarks.{name}")
         try:
@@ -55,7 +56,10 @@ def main() -> None:
                 row.print()
         except Exception as e:
             print(f"{name}/ERROR,0,{type(e).__name__}={e}")
+            failed.append(name)
     print(f"# total {time.time()-t0:.1f}s", file=sys.stderr)
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

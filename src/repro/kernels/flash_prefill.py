@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 _NEG_INF = -1e30
 
 
@@ -101,7 +99,7 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 DEFAULT_PAGE_SIZE = 16
 _NEG_INF = -1e30
 
@@ -108,7 +106,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         functools.partial(_kernel, page_size=page_size, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, lengths, q, k_pool, v_pool)

@@ -4,8 +4,9 @@ local autoscaler closed-loop on measured ITL/throughput.
   PYTHONPATH=src python -m repro.launch.serve --arch granite-8b \
       --requests 24 --max-slots 8 --itl-slo 0.5
 
-Uses the reduced (smoke) model variant on CPU; on TPU the same code path
-serves the full config (params sharded per launch.shardings).
+Serves the reduced (smoke) model variant by default, or the full config
+with ``--full-config``, as one unsharded float32 engine on JAX's default
+device. ``chip_smoke.py`` at the repo root is the full-width TPU check.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config
 from repro.core.backpressure import LocalMetrics
 from repro.core.local_autoscaler import LocalAutoscaler
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import Engine
 from repro.sim.workload import WorkloadSpec, generate
 
@@ -32,6 +34,7 @@ def main() -> None:
                     help="use the full assigned config (TPU-scale)")
     ap.add_argument("--autoscale-every", type=int, default=5)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch) if args.full_config \
         else get_smoke_config(args.arch)

@@ -58,12 +58,21 @@ class Engine:
                  max_batch_size: Optional[int] = None,
                  clock=time.monotonic, dtype=jnp.float32,
                  prefix_cache_entries: int = 0,
-                 prefill_chunk: int = 0):
+                 prefill_chunk: int = 0,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
         self.model = Model(cfg)
         self.dtype = dtype
+        # the params copy and the slot pool live on ``device`` (the first
+        # of jax.devices() when None); every step then runs there, since
+        # the committed params pull the computation onto their device
+        self.device = device or jax.devices()[0]
         key = key if key is not None else jax.random.PRNGKey(0)
-        self.params = params if params is not None else self.model.init(key, dtype=dtype)
+        with jax.default_device(self.device):
+            params = params if params is not None else \
+                self.model.init(key, dtype=dtype)
+            self.params = jax.device_put(params, self.device)
+            self.pool = self.model.init_cache(max_slots, max_len, dtype=dtype)
         self.max_slots = max_slots
         self.max_len = max_len
         self.max_batch_size = max_batch_size or max_slots
@@ -75,7 +84,6 @@ class Engine:
         if prefix_cache_entries > 0 and chunkable:
             from repro.serving.prefix_cache import PrefixCache
             self.prefix_cache = PrefixCache(prefix_cache_entries)
-        self.pool = self.model.init_cache(max_slots, max_len, dtype=dtype)
         self.slots: List[_Slot] = [_Slot() for _ in range(max_slots)]
         self.waiting: Deque[Request] = deque()
         self._decode = jax.jit(self.model.decode_step)
@@ -260,6 +268,9 @@ class Engine:
             for s in self.slots])[:, None]
         logits, self.pool = self._decode(self.params, tokens, self.pool)
         next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        # dispatch returns before the device finishes: wait, so the ITL the
+        # local autoscaler reads is the device step and not the enqueue
+        next_tok.block_until_ready()
         t_end = self.clock()
         itl = (t_end - self._last_step_t) if self._last_step_t else (t_end - now)
         self._last_step_t = t_end

@@ -1,8 +1,11 @@
 """Real-plane serving cluster: the SAME ChironController that drives the
 simulator drives actual JAX engines here (duck-typed to the SimInstance /
-SimCluster protocol the controllers use). This is Chiron in its deployable
-form — on CPU with reduced models in this container, on TPU meshes with
-the full configs via the identical code path.
+SimCluster protocol the controllers use). Each instance is one unsharded
+float32 ``Engine`` replica placed on a device of its own from
+``jax.devices()`` (instances share a device once there are more instances
+than devices, as on a one-device CPU host). ``chip_smoke.py`` serves
+mamba2-1.3b at full width this way on one TPU chip, and four replicas on
+four chips; sharded (multi-chip) instances are not implemented.
 
 Also implements Llumnix-style cross-instance request migration on top of
 the engine's slot read/restore (used for rebalancing mixed instances).
@@ -37,7 +40,8 @@ class RealInstance:
                  local_autoscaler: Optional[LocalAutoscaler] = None,
                  static_batch: Optional[int] = None,
                  load_time: float = 0.0, params=None, seed: int = 0,
-                 model: str = "llama-8b"):
+                 model: str = "llama-8b",
+                 device: Optional[jax.Device] = None):
         self.id = next(_inst_ids)
         self.cfg = cfg
         self.model = model           # served model (multi-model routing key)
@@ -52,7 +56,7 @@ class RealInstance:
                              max_batch_size=(local_autoscaler.max_batch_size
                                              if local_autoscaler
                                              else static_batch or max_slots),
-                             dtype=jnp.float32)
+                             dtype=jnp.float32, device=device)
         self._last_stats: Optional[StepStats] = None
         # slow-node health protocol (SimInstance parity): the routing
         # layer reads ``suspected_slow``; a real deployment would EWMA
@@ -87,7 +91,10 @@ class RealInstance:
 
     @property
     def n_running(self) -> int:
-        return self.engine.n_active
+        # an admitted request waits in the engine until its next step; it
+        # counts against the batch limit at once, as on a SimInstance, so
+        # one routing pass cannot hand every arrival to the same instance
+        return self.engine.n_active + self.engine.n_waiting
 
     @property
     def running(self):
@@ -95,7 +102,7 @@ class RealInstance:
         return [s for s in self.engine.slots if s.active]
 
     def slot_utilization(self) -> float:
-        return self.engine.n_active / max(self.max_batch_size, 1)
+        return self.n_running / max(self.max_batch_size, 1)
 
     def kv_utilization(self) -> float:
         return self.slot_utilization()
@@ -120,11 +127,10 @@ class RealInstance:
 
     # ------------------------------------------------ protocol: intake
     def can_admit(self, req: Request) -> bool:
-        if not self.active or self.n_running >= self.max_batch_size:
+        if not self.active or self.n_running >= \
+                min(self.max_batch_size, self.engine.max_slots):
             return False
-        if req.model != self.model:
-            return False            # never serve a wrong-model request
-        return self.engine._free_slot() is not None
+        return req.model == self.model   # never serve a wrong-model request
 
     def admit(self, req: Request, now: float) -> None:
         self.engine.submit(req)
@@ -167,9 +173,11 @@ class RealInstance:
 class RealCluster:
     """SimCluster-compatible manager over real engines.
 
-    Instances share one set of initialized params per model config (real
-    clusters load the same checkpoint); `load_time` models bring-up delay
-    in the driver's clock without sleeping.
+    Instances start from one set of initialized params per model config
+    (real clusters load the same checkpoint), copied to each instance's
+    device; instances on the device that holds them share them without a
+    copy. `load_time` models bring-up delay in the driver's clock without
+    sleeping.
     """
 
     def __init__(self, cfg: ModelConfig, *, max_chips: int = 64,
@@ -212,6 +220,12 @@ class RealCluster:
     def used_chips(self) -> int:
         return len(self.instances) * self.chips_per_instance
 
+    def _free_device(self) -> jax.Device:
+        """A device no live instance holds; when every device is taken
+        (more instances than devices), the least-shared one."""
+        held = [i.engine.device for i in self.instances]
+        return min(jax.devices(), key=held.count)
+
     def provision(self, model: str, itype: InstanceType, now: float,
                   **inst_kw) -> Optional[RealInstance]:
         if self.used_chips() + self.chips_per_instance > self.max_chips:
@@ -220,7 +234,7 @@ class RealCluster:
                             max_len=self.max_len,
                             load_time=self.load_time,
                             params=self._shared_params, model=model,
-                            **inst_kw)
+                            device=self._free_device(), **inst_kw)
         self.instances.append(inst)
         self.scale_ups += 1
         self.peak_chips = max(self.peak_chips, self.used_chips())
@@ -303,12 +317,14 @@ def serve_forever(requests: List[Request], controller, cluster: RealCluster,
                 inst.update_local_autoscaler()
         controller.route(cluster, queue, now)
         for inst in cluster.active_instances():
-            inst.step(now)
+            # the engine evicts batch work for a waiting interactive
+            # request; the victim goes back to the global queue
+            for r in inst.step(now).preempted:
+                queue.requeue(r)
         cluster.tick_accounting(0.0)
         steps += 1
         if pi >= len(pending) and len(queue) == 0 and \
-                all(i.n_running == 0 and i.engine.n_waiting == 0
-                    for i in cluster.instances):
+                all(i.n_running == 0 for i in cluster.instances):
             break
     done = [r for r in requests if r.state == RequestState.FINISHED]
     return {"steps": steps, "finished": len(done), "total": len(requests),
