@@ -43,6 +43,8 @@ from repro.core.local_autoscaler import LocalAutoscaler  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
 from repro.models import Model  # noqa: E402
+from repro.obs import FlightRecorder  # noqa: E402
+from repro.obs.host import jit_booking  # noqa: E402
 from repro.serving.engine import Engine  # noqa: E402
 from repro.serving.real_cluster import RealCluster, serve_forever  # noqa: E402
 from repro.serving.request import RequestState, RequestType  # noqa: E402
@@ -76,16 +78,13 @@ def device_info() -> dict:
             "count": len(devs)}
 
 
-class CompileClock:
-    """Seconds spent in XLA backend compiles since construction."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, secs: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
+def jit_record(jit: dict) -> str:
+    """The program's JIT counter (``repro.obs.host.jit_booking``) as one
+    phrase: XLA compiles apart from programs loaded from the compile
+    cache."""
+    return (f"XLA compile {jit['compile_s']:.1f} s, compile-cache loads "
+            f"{jit['load_s']:.1f} s ({jit['cache_hits']} hits, "
+            f"{jit['cache_misses']} misses)")
 
 
 def check_ssd_scan(cfg) -> None:
@@ -168,7 +167,7 @@ def recording_prefills(store: dict):
         Engine._prefill = served
 
 
-def serve(cfg, n_replicas: int, clock: CompileClock):
+def serve(cfg, n_replicas: int, rec: FlightRecorder):
     """Serve the seeded requests on ``n_replicas`` one-chip replicas;
     returns (per-request logits and devices in request order, the
     replicas' devices)."""
@@ -187,9 +186,11 @@ def serve(cfg, n_replicas: int, clock: CompileClock):
                                   init_batch=INIT_BATCH,
                                   max_batch=MAX_SLOTS))
     replicas = list(cluster.instances)
-    c0 = clock.seconds
+    jit0 = rec.jit_totals()
     with recording_prefills({}) as prefills:
-        out = serve_forever(reqs, ctrl, cluster, max_steps=5000)
+        out = serve_forever(reqs, ctrl, cluster, max_steps=5000,
+                            telemetry=rec)
+    jit = {k: v - jit0[k] for k, v in rec.jit_totals().items()}
     replicas = replicas or list(cluster.instances)
     holds = [sorted({str(d) for a in jax.tree.leaves(
         (i.engine.params, i.engine.pool)) for d in a.devices()})
@@ -199,7 +200,7 @@ def serve(cfg, n_replicas: int, clock: CompileClock):
     print(f"{n_replicas} replica(s) served {out['finished']}/{out['total']} "
           f"requests ({sum(r.is_interactive for r in reqs)} interactive), "
           f"{toks} tokens, {out['steps']} loop steps, wall "
-          f"{out['wall_s']:.1f} s, compile {clock.seconds - c0:.1f} s, "
+          f"{out['wall_s']:.1f} s, {jit_record(jit)}, "
           f"median step gap {itl * 1e3:.1f} ms, "
           f"scale-ups {out['scale_ups']}, replica devices {holds}")
     for r in reqs:
@@ -219,18 +220,18 @@ def serve(cfg, n_replicas: int, clock: CompileClock):
             for r in reqs], holds
 
 
-def one_chip(cfg, clock: CompileClock) -> None:
+def one_chip(cfg, rec: FlightRecorder) -> None:
     check_ssd_scan(cfg)
     check_prefill_uses_kernel(cfg)
-    serve(cfg, 1, clock)
+    serve(cfg, 1, rec)
 
 
-def four_chips(cfg, clock: CompileClock) -> None:
+def four_chips(cfg, rec: FlightRecorder) -> None:
     if len(jax.devices()) != 4:
         fail(f"--four-chips needs 4 devices, JAX sees {len(jax.devices())}")
-    ref, _ = serve(cfg, 1, clock)
+    ref, _ = serve(cfg, 1, rec)
     gc.collect()        # free the one-replica cluster before the next
-    got, holds = serve(cfg, 4, clock)
+    got, holds = serve(cfg, 4, rec)
     if any(len(h) != 1 for h in holds) or \
             len({h[0] for h in holds}) != 4:
         fail(f"replicas do not sit on four distinct devices: {holds}")
@@ -260,13 +261,14 @@ def main() -> None:
     print(f"model {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
           f"{cfg.param_count() / 1e9:.2f} B params, float32, "
           f"{MAX_SLOTS} slots x {MAX_LEN} positions")
-    clock = CompileClock()
+    rec = FlightRecorder()
     # repro-lint: ok(DET202, wall time of a chip run, printed as a record)
     t0 = time.monotonic()
-    (four_chips if args.four_chips else one_chip)(cfg, clock)
+    with jit_booking(rec):
+        (four_chips if args.four_chips else one_chip)(cfg, rec)
     # repro-lint: ok(DET202, wall time of a chip run, printed as a record)
     wall = time.monotonic() - t0
-    print(f"total {wall:.1f} s, of which XLA compile {clock.seconds:.1f} s")
+    print(f"total {wall:.1f} s, of which {jit_record(rec.jit_totals())}")
     print(json.dumps({"ok": True, "device": dev}))
 
 

@@ -1,5 +1,7 @@
 """Observability plane: columnar flight recorder, decision ledger,
-request-lifecycle tracing and exporters (see ``repro.obs.recorder``).
+request-lifecycle tracing and exporters (see ``repro.obs.recorder``), and
+the real serving path's host spans and JIT counter (``repro.obs.host``,
+which imports JAX and so is not imported here).
 
 Engines gate on :func:`resolve` (``telemetry=`` argument or the
 ``CHIRON_TELEMETRY`` environment variable); exports live in

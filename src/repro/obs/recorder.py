@@ -27,6 +27,13 @@ Three coordinated layers, all preallocated amortized-doubling columns
   boundaries are joined from the request ledger at export time, so the
   hot path pays exactly two optional appends per request.
 
+* **Host spans of the real serving path** — one row per closed span of
+  ``serve_forever`` and ``Engine`` (``repro.obs.host.span``), stamped on
+  the profiler's host clock (``CLOCK_REALTIME``, ``time.time_ns``) with
+  its parent, instance and request, and the JIT work (tracing, lowering,
+  compiling, compile-cache loads) booked to it while it was the innermost
+  open span (``repro.obs.host.jit_booking``).
+
 Gating mirrors ``repro.analysis.shadow``: engines call :func:`resolve`
 on their ``telemetry`` argument — a :class:`FlightRecorder` passes
 through, ``True`` builds one, ``None`` consults ``CHIRON_TELEMETRY``.
@@ -35,6 +42,7 @@ and results are bit-identical to a build without the recorder.
 """
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -47,11 +55,11 @@ _INF = float("inf")
 # new kinds append at the end so existing codes never shift)
 (PROVISION, RETIRE, FAIL, DEGRADE, RECOVER, EVICT, MIGRATE, HANDBACK,
  DRAIN, OUTAGE, RESTORE, FLASH, REJECT, SHED, EXPIRE, BREAKER,
- BROWNOUT) = range(17)
+ BROWNOUT, BATCH_LIMIT) = range(18)
 KIND_NAMES = ("provision", "retire", "fail", "degrade", "recover",
               "evict", "migrate", "handback", "drain", "outage",
               "restore", "flash", "reject", "shed", "expire", "breaker",
-              "brownout")
+              "brownout", "batch_limit")
 
 # int8 decision reasons: which control-law term fired. BOOTSTRAP covers
 # warm starts and the controller's keep-a-foothold provisions (step 0);
@@ -63,17 +71,26 @@ KIND_NAMES = ("provision", "retire", "fail", "degrade", "recover",
 # INFEASIBLE (admission estimated the TTFT unreachable), DEADLINE (the
 # queued request's deadline passed), RETRY_EXHAUSTED (client gave up),
 # BREAKER (circuit-breaker transition), OVERLOAD (brownout hysteresis).
+# LOCAL_BP marks an instance's local autoscaler moving its batch limit on
+# the inter-token latency it measured.
 (R_BOOTSTRAP, R_IBP_HIGH, R_IBP_LOW, R_BBP_ADD, R_BBP_IDLE, R_BBP_TRIM,
  R_PREEMPT, R_INJECTED, R_PLACEMENT, R_OUTAGE, R_FLASH, R_INFEASIBLE,
- R_DEADLINE, R_RETRY_EXHAUSTED, R_BREAKER, R_OVERLOAD) = range(16)
+ R_DEADLINE, R_RETRY_EXHAUSTED, R_BREAKER, R_OVERLOAD,
+ R_LOCAL_BP) = range(17)
 REASON_NAMES = ("bootstrap", "ibp_high", "ibp_low", "bbp_add",
                 "bbp_idle", "bbp_trim", "preempt", "injected",
                 "placement", "outage", "flash", "infeasible", "deadline",
-                "retry_exhausted", "breaker", "overload")
+                "retry_exhausted", "breaker", "overload", "local_bp")
 
 # int8 span events
 SPAN_ADMIT, SPAN_PREEMPT = 0, 1
 SPAN_NAMES = ("admit", "preempt")
+
+# JIT work booked to host spans: tracing to a jaxpr, lowering to an MLIR
+# module, an XLA compile, and a program loaded from the persistent
+# compilation cache (JAX reports that as a backend compile too)
+JIT_TRACE, JIT_LOWER, JIT_COMPILE, JIT_LOAD = range(4)
+JIT_NAMES = ("trace", "lower", "compile", "load")
 
 
 class _Columns:
@@ -192,9 +209,10 @@ class DecisionColumns(_Columns):
     """One row per control-plane action. ``value``/``threshold`` carry
     the fired term's backpressure reading and band edge (NaN when the
     action has no scalar input — e.g. injected failures); ``peer`` is
-    the destination cluster of a hand-back (-1 otherwise); ``count`` is
-    the multiplicity of aggregate actions (hand-back moves, drained
-    requests)."""
+    the destination cluster of a hand-back (a batch-limit row's instance,
+    see :meth:`FlightRecorder.record_batch_limit`; -1 otherwise);
+    ``count`` is the multiplicity of aggregate actions (hand-back moves,
+    drained requests)."""
     _COLUMNS = (
         ("t", np.float64, 0.0), ("cluster", np.int32, 0),
         ("kind", np.int8, 0), ("reason", np.int8, 0),
@@ -213,6 +231,75 @@ class SpanColumns(_Columns):
         ("t", np.float64, 0.0), ("row", np.int64, -1),
         ("event", np.int8, 0), ("instance", np.int32, -1),
     )
+
+
+def _union_ns(intervals) -> int:
+    """Length of the union of ``(start, end)`` intervals: JAX's events
+    nest (a jit traced inside another's trace), so their durations do not
+    add."""
+    total = 0
+    end = None
+    for s, e in sorted(intervals):
+        if end is None or s > end:
+            total += e - s
+            end = e
+        elif e > end:
+            total += e - end
+            end = e
+    return total
+
+
+class JitTally:
+    """JIT events booked to one span (or to no span): each as a
+    ``(category, start_ns, end_ns)`` interval, plus compile-cache hits and
+    misses."""
+
+    __slots__ = ("intervals", "hits", "misses")
+
+    def __init__(self):
+        self.intervals: list = []
+        self.hits = 0
+        self.misses = 0
+
+    def totals(self) -> tuple:
+        """Nanoseconds of trace, lower, compile and load, each the union
+        of its intervals, then the union of all four, hits and misses."""
+        by = [[] for _ in JIT_NAMES]
+        for cat, s, e in self.intervals:
+            by[cat].append((s, e))
+        return (*(_union_ns(iv) for iv in by),
+                _union_ns([(s, e) for _, s, e in self.intervals]),
+                self.hits, self.misses)
+
+
+class HostSpanColumns(_Columns):
+    """One row per closed host span of the real serving path, in the
+    order the spans closed (a child before its parent). ``id`` numbers
+    spans in the order they opened and ``parent`` is the enclosing
+    span's ``id`` (-1 at the top). ``t0``/``t1`` are ``time.time_ns()``
+    (``CLOCK_REALTIME``), the clock the JAX profiler stamps host events
+    with. ``request`` is the request's ``req_id`` (-1 for spans of no one
+    request), ``arg`` one integer the site names (the prompt length of a
+    prefill). The ``*_ns`` and cache columns hold the JIT work booked to
+    the span while it was the innermost open span; ``jit_ns`` is the
+    union of the four, so nested or overlapping events count once.
+    ``cache_misses`` counts JAX's event of that name, which it reports
+    when it writes a new program to the persistent cache: a compile
+    quicker than ``jax_persistent_cache_min_compile_time_secs`` is a
+    compile but no miss."""
+    _COLUMNS = (
+        ("id", np.int64, -1), ("name", np.int16, 0),
+        ("t0", np.int64, 0), ("t1", np.int64, 0),
+        ("parent", np.int64, -1), ("instance", np.int32, -1),
+        ("request", np.int64, -1), ("arg", np.int64, -1),
+        ("trace_ns", np.int64, 0), ("lower_ns", np.int64, 0),
+        ("compile_ns", np.int64, 0), ("load_ns", np.int64, 0),
+        ("jit_ns", np.int64, 0),
+        ("cache_hits", np.int32, 0), ("cache_misses", np.int32, 0),
+    )
+
+
+_NO_JIT = (0, 0, 0, 0, 0, 0, 0)
 
 
 class FlightRecorder:
@@ -239,7 +326,10 @@ class FlightRecorder:
                  "model_names", "_model_codes",
                  "itype_names", "_itype_codes",
                  "_ctx_reason", "_ctx_value", "_ctx_threshold",
-                 "inj_reason")
+                 "inj_reason",
+                 "host_spans", "_hs_stage", "host_span_names",
+                 "_host_span_codes", "_hs_open", "_hs_next",
+                 "jit_unspanned", "_hit_pending")
 
     def __init__(self, *, span_sample: float = 0.25, span_seed: int = 0):
         self.signals = SignalColumns()
@@ -270,6 +360,18 @@ class FlightRecorder:
         # crashes; the engines set R_OUTAGE around a correlated zone
         # outage so each victim's row carries the term that fired
         self.inj_reason = R_INJECTED
+        self.host_spans = HostSpanColumns()
+        self._hs_stage = self.host_spans._stage
+        self.host_span_names: List[str] = []
+        self._host_span_codes: Dict[str, int] = {}
+        # open host spans, innermost last: [id, name code, t0, parent id,
+        # instance, request, arg, JitTally or None]
+        self._hs_open: list = []
+        self._hs_next = 0
+        self.jit_unspanned = JitTally()
+        # a compile-cache hit was reported inside the backend compile
+        # that has not yet ended: that compile is a load
+        self._hit_pending = False
 
     # ------------------------------------------------------- vocabularies
     def register_cluster(self, cluster, name: str) -> int:
@@ -401,7 +503,7 @@ class FlightRecorder:
         """Interactive-over-batch preemption: one decision row (the saved
         KV size as ``value``) plus a sampled preempt span."""
         chips = cluster.used_chips()
-        saved = req.saved_kv[1] if req.saved_kv is not None else _NAN
+        saved = _saved_tokens(req.saved_kv)
         self.decisions.append(now, self._cluster_code(cluster), EVICT,
                               R_PREEMPT, self._model_code(req.model),
                               self._itype_code(inst.itype), saved, _NAN,
@@ -480,6 +582,22 @@ class FlightRecorder:
                               float(depth), threshold, chips, chips,
                               -1, 1)
 
+    def record_batch_limit(self, cluster, now: float, inst, before: int,
+                           after: int, itl: float = _NAN,
+                           itl_slo: float = _NAN) -> None:
+        """An instance's batch limit as its local autoscaler set it: the
+        inter-token latency it read (``value``) against the ITL SLO
+        (``threshold``). The limits before and after ride in
+        ``chips_before``/``chips_after`` and the instance id in ``peer``
+        (batch-limit rows move no chips and name no peer cluster). A row
+        with no reading (reason ``bootstrap``) gives the limit an
+        instance starts from."""
+        reason = R_BOOTSTRAP if itl != itl else R_LOCAL_BP
+        self.decisions.append(now, self._cluster_code(cluster), BATCH_LIMIT,
+                              reason, self._model_code(inst.model),
+                              self._itype_code(inst.itype), itl, itl_slo,
+                              before, after, inst.id, 1)
+
     # ---------------------------------------------------------- tick hooks
     def record_signals(self, now: float, cluster, model: str,
                        ibp: float, theta: float, bbp: int,
@@ -552,6 +670,76 @@ class FlightRecorder:
     def record_admit(self, now: float, row: int, inst_id: int) -> None:
         self.record_span(now, row, SPAN_ADMIT, inst_id)
 
+    # ---------------------------------------------------------- host spans
+    def host_span_code(self, name: str) -> int:
+        code = self._host_span_codes.get(name)
+        if code is None:
+            code = self._host_span_codes[name] = len(self.host_span_names)
+            self.host_span_names.append(name)
+        return code
+
+    def open_host_span(self, code: int, instance: int, request: int,
+                       arg: int) -> list:
+        stack = self._hs_open
+        row = [self._hs_next, code, time.time_ns(),
+               stack[-1][0] if stack else -1, instance, request, arg, None]
+        self._hs_next += 1
+        stack.append(row)
+        return row
+
+    def close_host_span(self, row: list) -> None:
+        t1 = time.time_ns()
+        stack = self._hs_open
+        if stack[-1] is row:
+            stack.pop()
+        else:               # closed out of order: an exception unwound it
+            stack.remove(row)
+        jit = row[7]
+        self._hs_stage.append((row[0], row[1], row[2], t1, row[3], row[4],
+                               row[5], row[6],
+                               *(_NO_JIT if jit is None
+                                 else jit.totals())))
+
+    def _jit_target(self) -> JitTally:
+        stack = self._hs_open
+        if not stack:
+            return self.jit_unspanned
+        top = stack[-1]
+        if top[7] is None:
+            top[7] = JitTally()
+        return top[7]
+
+    def book_jit(self, category: int, start: float, end: float) -> None:
+        """One JIT event, ``start``/``end`` in ``time.time()`` seconds,
+        booked to the innermost open host span (or to no span). A backend
+        compile inside which a compile-cache hit was reported is a load."""
+        if category == JIT_COMPILE and self._hit_pending:
+            category = JIT_LOAD
+            self._hit_pending = False
+        self._jit_target().intervals.append(
+            (category, int(start * 1e9), int(end * 1e9)))
+
+    def book_cache(self, hit: bool) -> None:
+        tally = self._jit_target()
+        if hit:
+            tally.hits += 1
+            self._hit_pending = True
+        else:
+            tally.misses += 1
+
+    def jit_totals(self) -> Dict[str, float]:
+        """Seconds of trace, lower, compile and load, the seconds of all
+        JIT work, and cache hits and misses, over every closed host span
+        and the work booked to no span."""
+        cols = self.host_spans
+        free = self.jit_unspanned.totals()
+        out = {f"{name}_s": (int(cols.col(f"{name}_ns").sum()) + free[i])
+               * 1e-9 for i, name in enumerate(JIT_NAMES)}
+        out["jit_s"] = (int(cols.col("jit_ns").sum()) + free[4]) * 1e-9
+        out["cache_hits"] = int(cols.col("cache_hits").sum()) + free[5]
+        out["cache_misses"] = int(cols.col("cache_misses").sum()) + free[6]
+        return out
+
     # -------------------------------------------------------------- replay
     def replay(self) -> Dict[str, int]:
         """Reconstruct the run's scale-action totals from the decision
@@ -609,6 +797,18 @@ class FlightRecorder:
             out[:, cls] = (np.searchsorted(adds, times, side="right")
                            - np.searchsorted(subs, times, side="right"))
         return out
+
+
+def _saved_tokens(saved_kv) -> float:
+    """Context tokens a preempted request carries: the simulator saves
+    ``("sim", tokens)``, the real engine its slot's cache (``pos`` holds
+    the context length)."""
+    if saved_kv is None:
+        return _NAN
+    if isinstance(saved_kv, tuple):
+        return saved_kv[1]
+    pos = saved_kv.get("pos")
+    return _NAN if pos is None else float(pos[0])
 
 
 def resolve(telemetry) -> Optional[FlightRecorder]:

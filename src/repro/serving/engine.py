@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import Model
+from repro.obs.host import span
 from repro.serving.request import Request, RequestState, RequestType
 
 _SCALAR_KEYS = ("pos",)
@@ -53,6 +54,12 @@ class _Slot:
 
 
 class Engine:
+    # flight recorder (repro.obs) handed over by RealCluster when telemetry
+    # is armed, and the serving instance's id its span rows carry; with
+    # None each span site costs a profiler annotation and one branch
+    obs = None
+    instance_id = -1
+
     def __init__(self, cfg: ModelConfig, *, key=None, params=None,
                  max_slots: int = 8, max_len: int = 256,
                  max_batch_size: Optional[int] = None,
@@ -186,40 +193,46 @@ class Engine:
         """Prefill a prompt, via the prefix cache and/or in chunks when
         those knobs are enabled; returns (last_logits, cache)."""
         toks = self._prompt_tokens(req)
-        past = None
-        if self.prefix_cache is not None:
-            past, consumed = self.prefix_cache.lookup(toks)
-            remaining = toks[consumed:]
-        else:
-            remaining = toks
-        chunk = self.prefill_chunk or len(remaining)
-        logits = None
-        for lo in range(0, len(remaining), chunk):
-            piece = remaining[lo:lo + chunk]
-            logits, past = self.model.prefill(
-                self.params, self._prompt_batch(req, piece),
-                dtype=self.dtype, past_cache=past)
-        if self.prefix_cache is not None:
-            self.prefix_cache.store(toks, past)
+        with span("engine.prefill", self.obs, self.instance_id, req.req_id,
+                  len(toks)):
+            past = None
+            if self.prefix_cache is not None:
+                past, consumed = self.prefix_cache.lookup(toks)
+                remaining = toks[consumed:]
+            else:
+                remaining = toks
+            chunk = self.prefill_chunk or len(remaining)
+            logits = None
+            for lo in range(0, len(remaining), chunk):
+                piece = remaining[lo:lo + chunk]
+                logits, past = self.model.prefill(
+                    self.params, self._prompt_batch(req, piece),
+                    dtype=self.dtype, past_cache=past)
+            if self.prefix_cache is not None:
+                self.prefix_cache.store(toks, past)
         return logits, past
 
     def _admit(self, req: Request, now: float) -> bool:
         slot = self._free_slot()
         if slot is None:
             return False
-        if req.saved_kv is not None:
-            self._restore_slot(slot, req.saved_kv)
-            req.saved_kv = None
-            tok = jnp.zeros((1,), jnp.int32)
-        else:
-            logits, cache = self._prefill(req)
-            self._write_slot(slot, jax.tree.map(lambda a: a, cache))
-            tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            req.tokens_generated += 1
-            if req.first_token_time is None:
-                req.first_token_time = now
-        req.state = RequestState.RUNNING
-        self.slots[slot] = _Slot(req, tok)
+        obs, iid, rid = self.obs, self.instance_id, req.req_id
+        with span("engine.admit", obs, iid, rid):
+            if req.saved_kv is not None:
+                with span("engine.restore", obs, iid, rid):
+                    self._restore_slot(slot, req.saved_kv)
+                req.saved_kv = None
+                tok = jnp.zeros((1,), jnp.int32)
+            else:
+                logits, cache = self._prefill(req)
+                with span("engine.slot_write", obs, iid, rid):
+                    self._write_slot(slot, jax.tree.map(lambda a: a, cache))
+                tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                req.tokens_generated += 1
+                if req.first_token_time is None:
+                    req.first_token_time = now
+            req.state = RequestState.RUNNING
+            self.slots[slot] = _Slot(req, tok)
         return True
 
     def preempt_one_batch(self, now: float) -> Optional[Request]:
@@ -228,7 +241,9 @@ class Engine:
             s = self.slots[i]
             if s.active and s.request.request_type == RequestType.BATCH:
                 req = s.request
-                req.saved_kv = self._read_slot(i)
+                with span("engine.preempt", self.obs, self.instance_id,
+                          req.req_id):
+                    req.saved_kv = self._read_slot(i)
                 req.state = RequestState.PREEMPTED
                 req.preemptions += 1
                 self.slots[i] = _Slot()
@@ -237,63 +252,80 @@ class Engine:
 
     # ------------------------------------------------------------ step
     def step(self) -> StepStats:
-        now = self.clock()
-        stats = StepStats(now=now, n_active=0, new_tokens=0)
-
-        # 1. admit (interactive first — zero-queuing), preempting batch
-        #    requests on a full instance if an interactive request waits.
-        self.waiting = deque(sorted(
-            self.waiting, key=lambda r: (not r.is_interactive, r.arrival_time)))
-        while self.waiting and self.n_active < self.max_batch_size:
-            req = self.waiting[0]
-            if not self._admit(req, now):
-                break
-            self.waiting.popleft()
-        if self.waiting and self.waiting[0].is_interactive and \
-                self.n_active >= self.max_batch_size:
-            victim = self.preempt_one_batch(now)
-            if victim is not None:
-                stats.preempted.append(victim)
-                self._admit(self.waiting.popleft(), now)
-
-        active_idx = [i for i, s in enumerate(self.slots) if s.active]
-        stats.n_active = len(active_idx)
-        if not active_idx:
+        if not self.waiting and not self.n_active:
+            # nothing to serve: no spans, so an idle serving loop's
+            # passes leave no rows and no profiler events
+            now = self.clock()
             self._last_step_t = now
-            return stats
+            return StepStats(now=now, n_active=0, new_tokens=0)
+        obs, iid = self.obs, self.instance_id
+        with span("engine.step", obs, iid):
+            now = self.clock()
+            stats = StepStats(now=now, n_active=0, new_tokens=0)
 
-        # 2. one decode iteration over the whole slot pool
-        tokens = jnp.stack([
-            s.token[0] if s.active else jnp.zeros((), jnp.int32)
-            for s in self.slots])[:, None]
-        logits, self.pool = self._decode(self.params, tokens, self.pool)
-        next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        # dispatch returns before the device finishes: wait, so the ITL the
-        # local autoscaler reads is the device step and not the enqueue
-        next_tok.block_until_ready()
-        t_end = self.clock()
-        itl = (t_end - self._last_step_t) if self._last_step_t else (t_end - now)
-        self._last_step_t = t_end
-        stats.itl = itl
+            # 1. admit (interactive first — zero-queuing), preempting batch
+            #    requests on a full instance if an interactive request waits.
+            with span("engine.schedule", obs, iid):
+                self.waiting = deque(sorted(
+                    self.waiting,
+                    key=lambda r: (not r.is_interactive, r.arrival_time)))
+                while self.waiting and self.n_active < self.max_batch_size:
+                    req = self.waiting[0]
+                    if not self._admit(req, now):
+                        break
+                    self.waiting.popleft()
+                if self.waiting and self.waiting[0].is_interactive and \
+                        self.n_active >= self.max_batch_size:
+                    victim = self.preempt_one_batch(now)
+                    if victim is not None:
+                        stats.preempted.append(victim)
+                        self._admit(self.waiting.popleft(), now)
 
-        # 3. bookkeeping: ITL samples, finishes
-        for i in active_idx:
-            s = self.slots[i]
-            req = s.request
-            req.itl_samples.append(itl)
-            req.tokens_generated += 1
-            stats.new_tokens += 1
-            if req.first_token_time is None:
-                req.first_token_time = t_end
-            if req.tokens_generated >= req.output_len or \
-                    int(self.pool["pos"][i]) >= self.max_len - 1:
-                req.state = RequestState.FINISHED
-                req.finish_time = t_end
-                stats.finished.append(req)
-                self.slots[i] = _Slot()
-            else:
-                s.token = next_tok[i:i + 1]
+            active_idx = [i for i, s in enumerate(self.slots) if s.active]
+            stats.n_active = len(active_idx)
+            if not active_idx:
+                self._last_step_t = now
+                return stats
 
-        self._window.append((t_end, stats.new_tokens))
-        stats.throughput = self.throughput()
+            # 2. one decode iteration over the whole slot pool
+            with span("engine.stack", obs, iid):
+                tokens = jnp.stack([
+                    s.token[0] if s.active else jnp.zeros((), jnp.int32)
+                    for s in self.slots])[:, None]
+            with span("engine.decode", obs, iid):
+                logits, self.pool = self._decode(self.params, tokens,
+                                                 self.pool)
+                next_tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            # dispatch returns before the device finishes: wait, so the ITL
+            # the local autoscaler reads is the device step and not the
+            # enqueue
+            with span("engine.sync", obs, iid):
+                next_tok.block_until_ready()
+            t_end = self.clock()
+            itl = (t_end - self._last_step_t) if self._last_step_t \
+                else (t_end - now)
+            self._last_step_t = t_end
+            stats.itl = itl
+
+            # 3. bookkeeping: ITL samples, finishes
+            with span("engine.retire", obs, iid):
+                for i in active_idx:
+                    s = self.slots[i]
+                    req = s.request
+                    req.itl_samples.append(itl)
+                    req.tokens_generated += 1
+                    stats.new_tokens += 1
+                    if req.first_token_time is None:
+                        req.first_token_time = t_end
+                    if req.tokens_generated >= req.output_len or \
+                            int(self.pool["pos"][i]) >= self.max_len - 1:
+                        req.state = RequestState.FINISHED
+                        req.finish_time = t_end
+                        stats.finished.append(req)
+                        self.slots[i] = _Slot()
+                    else:
+                        s.token = next_tok[i:i + 1]
+
+                self._window.append((t_end, stats.new_tokens))
+                stats.throughput = self.throughput()
         return stats

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 # mirror-sync: module ok(real engine has no RequestLedger/InstancePlane)
 # The columnar mirrors exist only in the simulated data plane.
+import contextlib
 import itertools
 import time
 from typing import Callable, Dict, List, Optional
@@ -24,6 +25,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.local_autoscaler import LocalAutoscaler
 from repro.core.backpressure import LocalMetrics
+from repro.obs.host import jit_booking, span
+from repro.obs.recorder import resolve
 from repro.serving.engine import Engine, StepStats
 from repro.serving.request import Request, RequestState, RequestType
 from repro.sim.cluster import SLOW_SUSPECT_RATIO, InstanceState, InstanceType
@@ -34,6 +37,11 @@ _inst_ids = itertools.count(1000)
 
 class RealInstance:
     """Engine + instance type + local autoscaler; SimInstance-compatible."""
+
+    # the cluster that provisioned it, and the flight recorder (repro.obs)
+    # the cluster hands over while telemetry is armed
+    cluster = None
+    obs = None
 
     def __init__(self, cfg: ModelConfig, itype: InstanceType, now: float, *,
                  max_slots: int = 6, max_len: int = 128,
@@ -57,6 +65,7 @@ class RealInstance:
                                              if local_autoscaler
                                              else static_batch or max_slots),
                              dtype=jnp.float32, device=device)
+        self.engine.instance_id = self.id
         self._last_stats: Optional[StepStats] = None
         # slow-node health protocol (SimInstance parity): the routing
         # layer reads ``suspected_slow``; a real deployment would EWMA
@@ -136,25 +145,48 @@ class RealInstance:
         self.engine.submit(req)
 
     def evict_one_batch(self, now: float) -> Optional[Request]:
-        return self.engine.preempt_one_batch(now)
+        victim = self.engine.preempt_one_batch(now)
+        if victim is not None and self.obs is not None:
+            self.obs.record_evict(self.cluster, now, victim, self)
+        return victim
+
+    # ------------------------------------------------ telemetry
+    def attach(self, obs) -> None:
+        """Hand ``obs`` (a FlightRecorder, or None to detach) to this
+        instance and its engine; an armed recorder gets the batch limit
+        the instance holds now."""
+        self.obs = self.engine.obs = obs
+        if obs is not None:
+            limit = self.engine.max_batch_size
+            obs.record_batch_limit(self.cluster, self.cluster.now, self,
+                                   limit, limit)
 
     # ------------------------------------------------ execution
     def step(self, now: float) -> StepStats:
         stats = self.engine.step()
         self._last_stats = stats
+        if self.obs is not None:
+            for victim in stats.preempted:
+                self.obs.record_evict(self.cluster, now, victim, self)
         return stats
 
     def update_local_autoscaler(self) -> None:
         if self.local is None or self._last_stats is None or \
                 self._last_stats.n_active == 0:
             return
+        itl, slo = self._last_stats.itl, self.min_itl_slo()
+        before = self.engine.max_batch_size
         self.local.update(LocalMetrics(
-            observed_itl=self._last_stats.itl,
+            observed_itl=itl,
             throughput=max(self._last_stats.throughput, 1e-6),
-            itl_slo=self.min_itl_slo(),
+            itl_slo=slo,
             n_active=self._last_stats.n_active,
             batch_size=self.local.max_batch_size))
         self.engine.set_max_batch_size(self.local.max_batch_size)
+        if self.obs is not None and self.engine.max_batch_size != before:
+            self.obs.record_batch_limit(self.cluster, self.cluster.now,
+                                        self, before,
+                                        self.engine.max_batch_size, itl, slo)
 
     # ------------------------------------------------ migration
     def migrate_out(self, req_id: int) -> Optional[Request]:
@@ -194,6 +226,10 @@ class RealCluster:
         self.scale_downs = 0
         self.chip_seconds = 0.0
         self.peak_chips = 0
+        # serving time of the last loop pass (``serve_forever`` sets it),
+        # which stamps the decision rows of an armed recorder
+        self.now = 0.0
+        self.obs = None
         model_seed = jax.random.PRNGKey(0)
         from repro.models import Model
         self._shared_params = Model(cfg).init(model_seed, dtype=jnp.float32)
@@ -230,17 +266,24 @@ class RealCluster:
                   **inst_kw) -> Optional[RealInstance]:
         if self.used_chips() + self.chips_per_instance > self.max_chips:
             return None
+        chips0 = self.used_chips()
         inst = RealInstance(self.cfg, itype, now, max_slots=self.max_slots,
                             max_len=self.max_len,
                             load_time=self.load_time,
                             params=self._shared_params, model=model,
                             device=self._free_device(), **inst_kw)
+        inst.cluster = self
         self.instances.append(inst)
         self.scale_ups += 1
         self.peak_chips = max(self.peak_chips, self.used_chips())
+        if self.obs is not None:
+            self.obs.record_provision(self, now, model, itype, chips0,
+                                      self.used_chips())
+            inst.attach(self.obs)
         return inst
 
     def retire(self, inst: RealInstance) -> List[Request]:
+        chips0 = self.used_chips()
         displaced = []
         for i, s in enumerate(inst.engine.slots):
             if s.active:
@@ -252,7 +295,19 @@ class RealCluster:
         inst.state = InstanceState.RETIRED
         self.instances.remove(inst)
         self.scale_downs += 1
+        if self.obs is not None:
+            self.obs.record_retire(self, self.now, inst, chips0,
+                                   self.used_chips())
+            inst.attach(None)
         return displaced
+
+    def attach(self, obs) -> None:
+        """Hand ``obs`` (a FlightRecorder, or None to detach) to the
+        cluster, its instances and their engines; instances provisioned
+        later get it too."""
+        self.obs = obs
+        for inst in self.instances:
+            inst.attach(obs)
 
     def tick_accounting(self, dt: float) -> None:
         self.chip_seconds += self.used_chips() * dt
@@ -294,40 +349,80 @@ class RealCluster:
 
 def serve_forever(requests: List[Request], controller, cluster: RealCluster,
                   *, max_steps: int = 2000, control_every: int = 5,
-                  clock=None) -> Dict:
+                  clock=None, telemetry=None) -> Dict:
     """Drive a real cluster: arrivals -> controller.route (shared with the
-    sim) -> engine steps -> local autoscaler updates."""
+    sim) -> engine steps -> local autoscaler updates.
+
+    ``telemetry`` arms the flight recorder (``repro.obs``) as the
+    simulator's does: a ``FlightRecorder`` (or any truthy value, or
+    ``CHIRON_TELEMETRY=1`` when None) gets the controller's signals and
+    decisions, the cluster's provisions, retirements, evictions and batch
+    limits, one host span per loop pass and engine stage, and the JIT
+    work of each span; the result's ``"telemetry"`` holds it. Every pass
+    is a profiler annotation either way: ``serve.pass`` with its
+    ``serve.control`` and ``serve.route``, or, for a run of passes with
+    nothing queued and nothing running, one ``serve.wait``. ``clock`` is
+    read once per pass (and at the start and the end), never by a span."""
     from repro.serving.global_queue import GlobalQueue
     clock = clock or time.monotonic
+    rec = resolve(telemetry)
     t0 = clock()
     queue = GlobalQueue()
     pending = sorted(requests, key=lambda r: r.arrival_time)
     pi = 0
     steps = 0
-    while steps < max_steps:
-        now = clock() - t0
-        while pi < len(pending) and pending[pi].arrival_time <= now:
-            queue.push(pending[pi])
-            pi += 1
-        for inst in cluster.instances:
-            inst.activate_if_ready(now)
-        if steps % control_every == 0:
-            controller.control(cluster, queue, now)
-            for inst in cluster.active_instances():
-                inst.update_local_autoscaler()
-        controller.route(cluster, queue, now)
-        for inst in cluster.active_instances():
-            # the engine evicts batch work for a waiting interactive
-            # request; the victim goes back to the global queue
-            for r in inst.step(now).preempted:
-                queue.requeue(r)
-        cluster.tick_accounting(0.0)
-        steps += 1
-        if pi >= len(pending) and len(queue) == 0 and \
-                all(i.n_running == 0 for i in cluster.instances):
-            break
+    if rec is not None:
+        cluster.attach(rec)
+        controller.obs = rec
+    # the open serve.wait span of the current run of idle passes
+    wait = None
+    quiet = contextlib.nullcontext()
+    try:
+        with jit_booking(rec):
+            while steps < max_steps:
+                now = clock() - t0
+                cluster.now = now
+                while pi < len(pending) and pending[pi].arrival_time <= now:
+                    queue.push(pending[pi])
+                    pi += 1
+                idle = len(queue) == 0 and \
+                    all(i.n_running == 0 for i in cluster.instances)
+                if idle and wait is None:
+                    wait = span("serve.wait", rec)
+                    wait.__enter__()
+                elif not idle and wait is not None:
+                    wait.__exit__(None, None, None)
+                    wait = None
+                with quiet if idle else span("serve.pass", rec):
+                    for inst in cluster.instances:
+                        inst.activate_if_ready(now)
+                    if steps % control_every == 0:
+                        with quiet if idle else span("serve.control", rec):
+                            controller.control(cluster, queue, now)
+                            for inst in cluster.active_instances():
+                                inst.update_local_autoscaler()
+                    with quiet if idle else span("serve.route", rec):
+                        controller.route(cluster, queue, now)
+                    for inst in cluster.active_instances():
+                        # the engine evicts batch work for a waiting
+                        # interactive request; the victim goes back to
+                        # the global queue
+                        for r in inst.step(now).preempted:
+                            queue.requeue(r)
+                    cluster.tick_accounting(0.0)
+                steps += 1
+                if pi >= len(pending) and len(queue) == 0 and \
+                        all(i.n_running == 0 for i in cluster.instances):
+                    break
+    finally:
+        if wait is not None:
+            wait.__exit__(None, None, None)
+        if rec is not None:
+            cluster.attach(None)
+            controller.obs = None
     done = [r for r in requests if r.state == RequestState.FINISHED]
     return {"steps": steps, "finished": len(done), "total": len(requests),
             "wall_s": clock() - t0,
             "scale_ups": cluster.scale_ups,
-            "scale_downs": cluster.scale_downs}
+            "scale_downs": cluster.scale_downs,
+            "telemetry": rec}
