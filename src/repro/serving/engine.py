@@ -14,14 +14,16 @@ from __future__ import annotations
 
 # mirror-sync: module ok(real engine has no RequestLedger/InstancePlane)
 # The columnar mirrors exist only in the simulated data plane.
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Deque, Dict, List, Optional
+from typing import Callable, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ModelConfig
 from repro.models import Model
@@ -30,6 +32,23 @@ from repro.serving.request import Request, RequestState, RequestType
 
 _SCALAR_KEYS = ("pos",)
 _ROW_KEYS = ("slot_pos",)
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_program(cfg: ModelConfig, dtype) -> Callable:
+    """The jitted prefill of one (config, dtype), built once per process
+    and shared by every engine. ``jax.jit`` keeps one program per shape of
+    its arguments: the prompt's length (and, on the chunked path, the past
+    cache's), so an admission at a shape any engine has prefilled before
+    costs no tracing, lowering or loading. Params, prompt batch and past
+    cache are arguments, never constants of the program."""
+    model = Model(cfg)
+
+    def engine_prefill(params, batch, past_cache):
+        return model.prefill(params, batch, dtype=dtype,
+                             past_cache=past_cache)
+
+    return jax.jit(engine_prefill)
 
 
 @dataclass
@@ -93,7 +112,11 @@ class Engine:
             self.prefix_cache = PrefixCache(prefix_cache_entries)
         self.slots: List[_Slot] = [_Slot() for _ in range(max_slots)]
         self.waiting: Deque[Request] = deque()
-        self._decode = jax.jit(self.model.decode_step)
+        # shardings given, so that a step's committed tokens and pool run
+        # the program that a first call with uncommitted inputs lowered
+        self._decode = jax.jit(self.model.decode_step,
+                               in_shardings=SingleDeviceSharding(self.device))
+        self._prefill_program = prefill_program(cfg, jnp.dtype(dtype))
         self._last_step_t: Optional[float] = None
         self._window: Deque = deque(maxlen=32)   # (t, tokens) samples
         self._rng = np.random.default_rng(0)
@@ -180,7 +203,7 @@ class Engine:
 
     def _prompt_batch(self, req: Request, toks: Optional[np.ndarray] = None):
         toks = toks if toks is not None else self._prompt_tokens(req)
-        batch = {"tokens": jnp.asarray(toks)[None]}
+        batch = {"tokens": jnp.asarray(toks[None])}
         if self.cfg.arch_type == "audio":
             batch["frames"] = jnp.zeros((1, self.cfg.enc_seq, self.cfg.d_model),
                                         self.dtype)
@@ -205,9 +228,8 @@ class Engine:
             logits = None
             for lo in range(0, len(remaining), chunk):
                 piece = remaining[lo:lo + chunk]
-                logits, past = self.model.prefill(
-                    self.params, self._prompt_batch(req, piece),
-                    dtype=self.dtype, past_cache=past)
+                logits, past = self._prefill_program(
+                    self.params, self._prompt_batch(req, piece), past)
             if self.prefix_cache is not None:
                 self.prefix_cache.store(toks, past)
         return logits, past

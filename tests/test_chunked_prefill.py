@@ -74,3 +74,29 @@ def test_prefix_reuse_then_decode():
         np.testing.assert_allclose(np.asarray(da), np.asarray(dr),
                                    atol=5e-3, rtol=5e-3)
         tok = jnp.argmax(da, -1)[:, None].astype(jnp.int32)
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "qwen2-moe-a2.7b"])
+def test_engine_chunked_prefill_serves_the_eager_tokens(arch, served_tokens):
+    """An engine prefilling in chunks through its jitted program (one per
+    chunk and past length) serves the tokens it served with an eager
+    ``Model.prefill`` per chunk."""
+    from repro.serving.engine import Engine
+    from repro.serving.request import make_interactive
+    cfg = get_smoke_config(arch)
+    params = get_model(cfg).init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+               for n in (29, 21)]   # chunks 8+8+8+5 and 8+8+5
+
+    def serve(eager):
+        eng = Engine(cfg, params=params, max_slots=2, max_len=64,
+                     dtype=jnp.float32, prefill_chunk=8)
+        reqs = [make_interactive(len(p), 6) for p in prompts]
+        for r, p in zip(reqs, prompts):
+            r.prompt_tokens = p
+        return served_tokens(eng, reqs, eager=eager)
+
+    jitted = serve(eager=False)
+    assert [len(t) for t in jitted] == [6, 6]
+    assert jitted == serve(eager=True)

@@ -1,5 +1,6 @@
 """Real continuous-batching engine: e2e serving, preemption, KV restore."""
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
@@ -77,3 +78,31 @@ def test_throughput_metric_positive(engine_cfg):
     for _ in range(10):
         eng.step()
     assert eng.throughput() > 0
+
+
+FAMILIES = {"ssm": "mamba2-1.3b", "dense": "granite-8b",
+            "moe": "qwen2-moe-a2.7b", "hybrid": "zamba2-2.7b",
+            "audio": "whisper-base", "vlm": "internvl2-2b"}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_engine_prefill_matches_eager_model_prefill(family):
+    """The engine's jitted prefill computes what an eager ``Model.prefill``
+    does on the same params and prompt: the logits and every cache leaf,
+    within float32 rounding."""
+    cfg = get_smoke_config(FAMILIES[family])
+    assert cfg.arch_type == family
+    eng = Engine(cfg, max_slots=2, max_len=64, dtype=jnp.float32)
+    req = make_interactive(24, 4)
+    req.prompt_tokens = np.random.default_rng(11).integers(
+        0, cfg.vocab_size, 24, dtype=np.int32)
+    logits, cache = eng._prefill(req)
+    want_logits, want_cache = eng.model.prefill(
+        eng.params, eng._prompt_batch(req), dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want_logits),
+                               rtol=1e-5, atol=1e-5)
+    assert sorted(cache) == sorted(want_cache)
+    for k in cache:
+        np.testing.assert_allclose(np.asarray(cache[k]),
+                                   np.asarray(want_cache[k]),
+                                   rtol=1e-5, atol=1e-5, err_msg=k)

@@ -78,3 +78,29 @@ def test_engine_chunked_prefill():
     _run_engine(eng, [r])
     assert r.state == RequestState.FINISHED
     assert r.tokens_generated >= r.output_len
+
+
+def test_engine_prefix_cache_serves_the_eager_tokens(served_tokens):
+    """With the prefix cache on, the jitted prefill (a past cache sliced
+    to the shared prefix, then the suffix) serves the tokens the eager
+    ``Model.prefill`` served, and the cache is hit."""
+    cfg = get_smoke_config("granite-8b")
+    shared = np.arange(10, 26, dtype=np.int32) % cfg.vocab_size
+    extras = ([1, 2, 3], [4, 5, 6, 7], [8, 9])
+
+    def serve(eager):
+        eng = Engine(cfg, max_slots=2, max_len=64, dtype=jnp.float32,
+                     prefix_cache_entries=8)
+        reqs = []
+        for extra in extras:
+            r = make_interactive(16 + len(extra), 5)
+            r.prompt_tokens = np.concatenate(
+                [shared, np.asarray(extra, np.int32)])
+            reqs.append(r)
+        tokens = served_tokens(eng, reqs, eager=eager)
+        assert eng.prefix_cache.hits == 2
+        return tokens
+
+    jitted = serve(eager=False)
+    assert all(len(t) == 5 for t in jitted)
+    assert jitted == serve(eager=True)

@@ -1,6 +1,7 @@
 """Host spans and the JIT counter on the real serving path (``repro.obs``
 armed through ``serve_forever`` and ``Engine``), on the mamba2 smoke
 configuration."""
+import dataclasses
 import glob
 import os
 
@@ -217,8 +218,8 @@ def test_forced_retrace_is_booked_to_its_span_as_a_load(cfg, compile_cache):
     eng.submit(_request(make_interactive, 16, 12, 4))
     with jit_booking(rec):
         eng.step()                  # admission, and the first decode
-        # the second decode compiles again for the pool the first one
-        # committed to the device; the third is warm
+        # the second decode finds the program of the first for the pool
+        # the first one committed to the device; the third is warm
         eng.step()
         eng.step()
         jax.clear_caches()          # the decode step must be traced again
@@ -245,6 +246,50 @@ def test_forced_retrace_is_booked_to_its_span_as_a_load(cfg, compile_cache):
     assert tot["load_s"] > 0 and tot["cache_hits"] >= 1
     assert tot["jit_s"] <= sum(tot[f"{k}_s"] for k in
                                ("trace", "lower", "compile", "load")) + 1e-9
+
+
+def test_engines_of_one_config_share_the_prefill_program(cfg):
+    """A second engine of the same config and dtype admits a prompt at a
+    length the first one prefilled without tracing, lowering or loading
+    anything; a length no engine has prefilled is traced and lowered."""
+    # a config no other test has prefilled in this process
+    cfg = dataclasses.replace(cfg, name="mamba2-shared-prefill")
+    rec = FlightRecorder()
+    first, second = (Engine(cfg, max_slots=2, max_len=128,
+                            dtype=jnp.float32) for _ in range(2))
+    assert first._prefill_program is second._prefill_program
+    first.obs = second.obs = rec
+    with jit_booking(rec):
+        for eng, n, seed in ((first, 16, 8), (second, 16, 9),
+                             (second, 24, 10)):
+            eng.submit(_request(make_interactive, n, 2, seed))
+            eng.step()
+    cold, shared, new = [r for r in _rows(rec)
+                         if r["name"] == "engine.prefill"]
+    assert [r["arg"] for r in (cold, shared, new)] == [16, 16, 24]
+    assert cold["trace_ns"] > 0 and cold["lower_ns"] > 0
+    assert shared["jit_ns"] == 0 and shared["load_ns"] == 0
+    assert shared["cache_hits"] == shared["cache_misses"] == 0
+    assert new["trace_ns"] > 0 and new["lower_ns"] > 0
+
+
+def test_a_warmed_decode_step_is_not_lowered_again(cfg):
+    """A decode step first called on uncommitted zeros, as a warm-up
+    does, runs the same program once the engine's own steps feed it the
+    committed tokens and pool: no step lowers or compiles again."""
+    rec = FlightRecorder()
+    eng = Engine(cfg, max_slots=2, max_len=128, dtype=jnp.float32)
+    jax.block_until_ready(eng._decode(
+        eng.params, jnp.zeros((2, 1), jnp.int32), eng.pool))
+    eng.obs = rec
+    eng.submit(_request(make_interactive, 16, 4, 11))
+    with jit_booking(rec):
+        for _ in range(3):
+            eng.step()
+    decodes = [r for r in _rows(rec) if r["name"] == "engine.decode"]
+    assert len(decodes) == 3
+    for r in decodes:
+        assert r["lower_ns"] == r["compile_ns"] == r["load_ns"] == 0, r
 
 
 def _cluster(cfg, slots=2):
