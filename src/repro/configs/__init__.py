@@ -22,6 +22,7 @@ _ARCH_MODULES = {
     "deepseek-moe-16b": "deepseek_moe_16b",
     "whisper-base": "whisper_base",
     "internvl2-2b": "internvl2_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     # the paper's own evaluation models (used by the simulator / perf model)
     "llama-8b": "llama_8b",
     "llama-70b": "llama_70b",

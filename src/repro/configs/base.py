@@ -9,6 +9,7 @@ CPU smoke tests: <=2 layers, d_model <= 512, <= 4 experts).
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 
 
@@ -44,13 +45,25 @@ class ModelConfig:
     head_dim: int = 0             # 0 -> d_model // n_heads
     norm: str = "rmsnorm"         # rmsnorm | layernorm | nonparametric
     ffn: str = "swiglu"           # swiglu | gelu
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0   # 0: no positional encoding (NoPE)
     tie_embeddings: bool = False
     moe: MoEConfig = field(default_factory=MoEConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
-    # hybrid (zamba2-style): a single shared attention block applied every
-    # `attn_every` backbone layers.
+    # hybrid, in one of two forms. Zamba2: one attention+FFN block, its
+    # weights shared, applied after every `attn_every` Mamba2 layers.
+    # Layer pattern (granitemoehybrid): `layer_types` names each layer
+    # "mamba" or "attention", every layer has weights of its own and an
+    # MLP after its mixer; `attn_every` is then 0.
     attn_every: int = 0
+    layer_types: tuple = ()
+    # muP multipliers (granitemoehybrid): token embeddings times
+    # `embedding_multiplier`, each residual branch times
+    # `residual_multiplier`, attention scores times `attention_multiplier`
+    # (0: 1/sqrt(head_dim)), logits divided by `logits_scaling`
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    logits_scaling: float = 1.0
     # encoder-decoder (whisper-style backbone)
     n_enc_layers: int = 0
     enc_seq: int = 0              # number of (stubbed) frame embeddings
@@ -67,6 +80,23 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // max(self.n_heads, 1)
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale of attention scores."""
+        return self.attention_multiplier or \
+            1.0 / math.sqrt(self.resolved_head_dim)
+
+    @property
+    def n_attention_layers(self) -> int:
+        """Layers that keep a KV cache."""
+        if self.arch_type == "ssm":
+            return 0
+        if self.layer_types:
+            return self.layer_types.count("attention")
+        if self.arch_type == "hybrid":
+            return self.n_layers // max(self.attn_every, 1)
+        return self.n_layers
 
     @property
     def d_inner(self) -> int:
@@ -114,6 +144,10 @@ class ModelConfig:
             n = self.n_layers * per_layer
         elif self.arch_type == "ssm":
             n = self.n_layers * self._ssm_layer_params()
+        elif self.arch_type == "hybrid" and self.layer_types:
+            n_attn = self.n_attention_layers
+            n = (self.n_layers - n_attn) * self._ssm_layer_params()
+            n += n_attn * (attn + d) + self.n_layers * (mlp + d)
         elif self.arch_type == "hybrid":
             n = self.n_layers * self._ssm_layer_params()
             # one shared attention block (attn + mlp), reused

@@ -1,11 +1,13 @@
 """Causal flash-attention prefill kernel (TPU Pallas).
 
-Chunked-prefill attention for serving instances: grid (batch, q_head,
-q_block, kv_block) with the kv_block axis sequential ("arbitrary") so a
-flash online-softmax accumulator can live in VMEM scratch. Blocks above the
+The attention of a whole prompt, on the served prefill path
+(``models.layers.attention_forward`` on TPU): grid (batch, q_head, q_block,
+kv_block) with the kv_block axis sequential ("arbitrary") so a flash
+online-softmax accumulator can live in VMEM scratch. Blocks above the
 causal diagonal are skipped with ``pl.when`` — both the DMA cost model and
 the FLOP count see only the lower triangle. GQA is handled by indexing the
-KV head as q_head // group in the BlockSpec index_map.
+KV head as q_head // group in the BlockSpec index_map. Its custom call is
+named ``flash_prefill`` in the compiled program and the profiler's trace.
 """
 from __future__ import annotations
 
@@ -66,18 +68,20 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s,
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "causal",
-                                             "interpret"))
+                                             "scale", "interpret"))
 def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   block_q: int = 256, block_k: int = 256,
-                  causal: bool = True, interpret: bool = False) -> jax.Array:
-    """Flash attention. q (B,H,S,D); k/v (B,Hkv,S,D); returns (B,H,S,D)."""
+                  causal: bool = True, scale: float | None = None,
+                  interpret: bool = False) -> jax.Array:
+    """Flash attention. q (B,H,S,D); k/v (B,Hkv,S,D); returns (B,H,S,D).
+    Scores are multiplied by ``scale`` (None: 1/sqrt(D))."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     group = H // Hkv
     block_q = min(block_q, S)
     block_k = min(block_k, S)
     assert S % block_q == 0 and S % block_k == 0
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     grid = (B, H, S // block_q, S // block_k)
 
     return pl.pallas_call(
@@ -103,4 +107,5 @@ def flash_prefill(q: jax.Array, k: jax.Array, v: jax.Array, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_prefill",
     )(q, k, v)

@@ -36,13 +36,27 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
 
 
 def flash_prefill(q, k, v, *, causal: bool = True, block_q: int = 256,
-                  block_k: int = 256, backend: Backend | None = None):
+                  block_k: int = 256, scale: float | None = None,
+                  backend: Backend | None = None):
+    """q (B,H,S,D); k/v (B,Hkv,S,D) -> (B,H,S,D); scores times ``scale``
+    (None: 1/sqrt(D))."""
     backend = backend or default_backend()
     if backend == "ref":
         return jax.jit(functools.partial(_ref.flash_prefill_ref,
-                                         causal=causal))(q, k, v)
+                                         causal=causal, scale=scale))(q, k, v)
+    s = q.shape[2]
+    pad = -s % min(max(block_q, block_k), s)
+    if causal and pad:
+        # pad the sequence to whole blocks: a padded key lies after every
+        # real query, so the causal mask hides it; padded rows are dropped
+        import jax.numpy as jnp
+        widths = ((0, 0), (0, 0), (0, pad), (0, 0))
+        return flash_prefill(jnp.pad(q, widths), jnp.pad(k, widths),
+                             jnp.pad(v, widths), causal=True,
+                             block_q=block_q, block_k=block_k, scale=scale,
+                             backend=backend)[:, :, :s]
     return _flash_kernel(q, k, v, causal=causal, block_q=block_q,
-                         block_k=block_k,
+                         block_k=block_k, scale=scale,
                          interpret=(backend == "interpret"))
 
 

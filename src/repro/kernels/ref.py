@@ -40,14 +40,17 @@ def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
 
 def flash_prefill_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                      causal: bool = True) -> jax.Array:
-    """Oracle for kernels.flash_prefill. q (B,H,S,D); k/v (B,Hkv,S,D)."""
+                      causal: bool = True,
+                      scale: float | None = None) -> jax.Array:
+    """Oracle for kernels.flash_prefill. q (B,H,S,D); k/v (B,Hkv,S,D);
+    scores times ``scale`` (None: 1/sqrt(D))."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     group = H // Hkv
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qg = q.reshape(B, Hkv, group, S, D)
     s = jnp.einsum("bkgsd,bktd->bkgst", qg.astype(jnp.float32),
-                   k.astype(jnp.float32)) / math.sqrt(D)
+                   k.astype(jnp.float32)) * scale
     if causal:
         mask = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
         s = jnp.where(mask[None, None, None], s, _NEG_INF)

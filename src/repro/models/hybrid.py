@@ -1,9 +1,25 @@
-"""Zamba2-style hybrid: Mamba2 backbone + one shared attention block applied
-every ``cfg.attn_every`` layers (each invocation keeps its own KV cache but
-re-uses the same weights). [arXiv:2411.15242]"""
+"""Hybrid Mamba2 + attention decoders, in two forms.
+
+- Zamba2 (``cfg.attn_every``): a Mamba2 backbone and one attention+FFN
+  block whose weights are shared, applied after every ``attn_every`` Mamba2
+  layers; each application keeps its own KV cache. [arXiv:2411.15242]
+- Layer pattern (``cfg.layer_types``, granitemoehybrid): each layer is a
+  Mamba2 or an attention mixer, named in order, with weights of its own,
+  and every mixer is followed by a SwiGLU MLP. A layer is
+  ``x + r * mixer(norm(x))`` then ``x + r * mlp(norm(x))``, with the muP
+  multipliers of the config: ``r`` the residual multiplier, the token
+  embeddings scaled by the embedding multiplier, attention scores by the
+  attention multiplier, the logits divided by the logits scaling.
+  Attention has no positional encoding where ``cfg.rope_theta`` is 0.
+
+Both keep two kinds of state in one cache: ``ssm``/``conv`` stacked over
+the Mamba2 layers and ``k``/``v`` over the attention layers. The mixers and
+MLPs run under ``jax.named_scope`` names ``mamba``, ``attention`` and
+``mlp``, which the compiled programs carry as op metadata.
+"""
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +36,41 @@ def _n_groups(cfg: ModelConfig) -> int:
     return cfg.n_layers // cfg.attn_every
 
 
+def _n_mamba(cfg: ModelConfig) -> int:
+    return cfg.n_layers - cfg.n_attention_layers if cfg.layer_types \
+        else cfg.n_layers
+
+
+def _segments(cfg: ModelConfig) -> List[Tuple[str, int, int, int]]:
+    """The layer pattern as runs: (kind, index of its first layer among
+    that kind's stacked weights, its first layer, layers in the run), a run
+    being consecutive Mamba2 layers or one attention layer."""
+    segs: List[Tuple[str, int, int, int]] = []
+    seen = {"mamba": 0, "attention": 0}
+    for i, kind in enumerate(cfg.layer_types[:cfg.n_layers]):
+        if kind not in seen:
+            raise ValueError(f"unknown layer type {kind!r}")
+        if kind == "mamba" and segs and segs[-1][0] == "mamba":
+            k, j, first, n = segs[-1]
+            segs[-1] = (k, j, first, n + 1)
+        else:
+            segs.append((kind, seen[kind], i, 1))
+        seen[kind] += 1
+    return segs
+
+
+def _rows(stacked: Params, lo: int, n: int) -> Params:
+    return jax.tree.map(lambda a: a[lo:lo + n], stacked)
+
+
+def _row(stacked: Params, i: int) -> Params:
+    return jax.tree.map(lambda a: a[i], stacked)
+
+
 def init_params(cfg: ModelConfig, key, dtype=None) -> Params:
     dtype = dtype or jnp.dtype(cfg.dtype)
+    if cfg.layer_types:
+        return _init_pattern(cfg, key, dtype)
     ke, kl, ks, kn = jax.random.split(key, 4)
     layer_keys = jax.random.split(kl, cfg.n_layers)
     stacked = jax.vmap(lambda k: init_mamba_layer(cfg, k, dtype))(layer_keys)
@@ -40,27 +89,152 @@ def init_params(cfg: ModelConfig, key, dtype=None) -> Params:
     }
 
 
+def _init_pattern(cfg: ModelConfig, key, dtype) -> Params:
+    """Mamba2 layers (their ``rms_w`` is the layer's input norm) and
+    attention layers stacked by kind, an MLP and its norm per layer."""
+    n_m, n_a = _n_mamba(cfg), cfg.n_attention_layers
+    ke, km, ka, kf = jax.random.split(key, 4)
+    d = cfg.d_model
+    return {
+        "emb": L.init_embeddings(cfg, ke, dtype),
+        "mamba": jax.vmap(lambda k: init_mamba_layer(cfg, k, dtype))(
+            jax.random.split(km, n_m)),
+        "attn": jax.vmap(lambda k: L.init_attention(cfg, k, dtype))(
+            jax.random.split(ka, n_a)),
+        "attn_norm": {"w": jnp.ones((n_a, d), dtype)},
+        "mlp": jax.vmap(lambda k: L.init_ffn(cfg, k, dtype))(
+            jax.random.split(kf, cfg.n_layers)),
+        "mlp_norm": {"w": jnp.ones((cfg.n_layers, d), dtype)},
+        "final_norm": {"w": jnp.ones((d,), dtype)},
+    }
+
+
+# ------------------------------------------------------- zamba2 form
+
+
 def _group_slice(stacked: Params, g: int, size: int) -> Params:
     return jax.tree.map(lambda a: a[g * size:(g + 1) * size], stacked)
 
 
 def _shared_block_forward(cfg: ModelConfig, sp: Params, x: jax.Array,
                           positions: jax.Array) -> jax.Array:
-    h = L.apply_norm(cfg, sp["norm1"], x)
-    x = x + L.attention_forward(cfg, sp["attn"], h, positions=positions)
-    h = L.apply_norm(cfg, sp["norm2"], x)
-    return x + L.ffn_forward(cfg, sp["ffn"], h)
+    with jax.named_scope("attention"):
+        h = L.apply_norm(cfg, sp["norm1"], x)
+        x = x + L.attention_forward(cfg, sp["attn"], h, positions=positions)
+    with jax.named_scope("mlp"):
+        h = L.apply_norm(cfg, sp["norm2"], x)
+        return x + L.ffn_forward(cfg, sp["ffn"], h)
+
+
+# -------------------------------------------------- layer-pattern form
+
+
+def _mlp(cfg: ModelConfig, fp: Params, norm_w: jax.Array,
+         x: jax.Array) -> jax.Array:
+    with jax.named_scope("mlp"):
+        h = L.rms_norm(x, norm_w)
+        return x + cfg.residual_multiplier * L.ffn_forward(cfg, fp, h)
+
+
+def _embed(cfg: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
+    x = L.embed(params["emb"], tokens)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _logits(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    x = L.rms_norm(x, params["final_norm"]["w"])
+    logits = L.unembed(params["emb"], x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
+def _pattern_stack(cfg: ModelConfig, params: Params, x: jax.Array, dtype):
+    """Every layer of the pattern over a whole sequence x (B,S,d). Returns
+    x and the states: Mamba2 ``ssm`` and ``conv`` stacked in layer order,
+    and each attention layer's K and V."""
+    hs, convs, ks, vs = [], [], [], []
+
+    def mamba_mlp(x, lp):
+        mp, fp, nw = lp
+        with jax.named_scope("mamba"):
+            x, h, conv = mamba_forward(cfg, mp, x)
+        return _mlp(cfg, fp, nw, x), (h, conv.astype(dtype))
+
+    for kind, j, first, n in _segments(cfg):
+        mlp = (_rows(params["mlp"], first, n),
+               params["mlp_norm"]["w"][first:first + n])
+        if kind == "mamba":
+            x, (h, conv) = L.layer_scan(
+                mamba_mlp, x, (_rows(params["mamba"], j, n),) + mlp)
+            hs.append(h)
+            convs.append(conv)
+            continue
+        with jax.named_scope("attention"):
+            h = L.rms_norm(x, params["attn_norm"]["w"][j])
+            o, k, v = L.attention_forward(cfg, _row(params["attn"], j), h,
+                                          return_kv=True)
+            x = x + cfg.residual_multiplier * o
+        x = _mlp(cfg, _row(params["mlp"], first),
+                 params["mlp_norm"]["w"][first], x)
+        ks.append(k.astype(dtype))
+        vs.append(v.astype(dtype))
+    return x, (hs, convs, ks, vs)
+
+
+def _pattern_decode(cfg: ModelConfig, params: Params, x: jax.Array,
+                    cache: Dict[str, jax.Array], pos: jax.Array,
+                    slot_pos: jax.Array):
+    """One token through every layer of the pattern; returns x and the
+    new ``ssm``, ``conv``, ``k`` and ``v``."""
+    hs, convs = [], []
+    k_all, v_all = cache["k"], cache["v"]
+
+    def mamba_mlp(x, inp):
+        mp, fp, nw, h, conv = inp
+        with jax.named_scope("mamba"):
+            x, h, conv = mamba_decode(cfg, mp, x, h, conv)
+        return _mlp(cfg, fp, nw, x), (h, conv)
+
+    for kind, j, first, n in _segments(cfg):
+        if kind == "mamba":
+            x, (h, conv) = L.layer_scan(mamba_mlp, x, (
+                _rows(params["mamba"], j, n), _rows(params["mlp"], first, n),
+                params["mlp_norm"]["w"][first:first + n],
+                cache["ssm"][j:j + n], cache["conv"][j:j + n]))
+            hs.append(h)
+            convs.append(conv)
+            continue
+        with jax.named_scope("attention"):
+            h = L.rms_norm(x, params["attn_norm"]["w"][j])
+            o, kc, vc = L.attention_decode(cfg, _row(params["attn"], j), h,
+                                           k_all[j], v_all[j], pos, slot_pos)
+            k_all, v_all = k_all.at[j].set(kc), v_all.at[j].set(vc)
+            x = x + cfg.residual_multiplier * o
+        x = _mlp(cfg, _row(params["mlp"], first),
+                 params["mlp_norm"]["w"][first], x)
+    return x, jnp.concatenate(hs), jnp.concatenate(convs), k_all, v_all
+
+
+# ------------------------------------------------------------- API
 
 
 def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
             remat: bool = False) -> Tuple[jax.Array, jax.Array]:
     B, S = tokens.shape
+    if cfg.layer_types:
+        x, _ = _pattern_stack(cfg, params, _embed(cfg, params, tokens),
+                              params["final_norm"]["w"].dtype)
+        return _logits(cfg, params, x), jnp.zeros((), jnp.float32)
     x = L.embed(params["emb"], tokens)
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     G, A = _n_groups(cfg), cfg.attn_every
 
     def mamba_body(x, lp):
-        x, _, _ = mamba_forward(cfg, lp, x)
+        with jax.named_scope("mamba"):
+            x, _, _ = mamba_forward(cfg, lp, x)
         return x, None
 
     step = jax.checkpoint(mamba_body) if remat else mamba_body
@@ -72,15 +246,16 @@ def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
 
 
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype) -> Dict[str, jax.Array]:
-    G = _n_groups(cfg)
+    n_attn = cfg.n_attention_layers
     H, P, N = cfg.n_ssm_heads, cfg.ssm.head_dim, cfg.ssm.state_dim
     ch = cfg.d_inner + 2 * N
     hd = cfg.resolved_head_dim
+    n_m = _n_mamba(cfg)
     return {
-        "ssm": jnp.zeros((cfg.n_layers, batch, H, P, N), jnp.float32),
-        "conv": jnp.zeros((cfg.n_layers, batch, cfg.ssm.conv_width - 1, ch), dtype),
-        "k": jnp.zeros((G, batch, cache_len, cfg.n_kv_heads, hd), dtype),
-        "v": jnp.zeros((G, batch, cache_len, cfg.n_kv_heads, hd), dtype),
+        "ssm": jnp.zeros((n_m, batch, H, P, N), jnp.float32),
+        "conv": jnp.zeros((n_m, batch, cfg.ssm.conv_width - 1, ch), dtype),
+        "k": jnp.zeros((n_attn, batch, cache_len, cfg.n_kv_heads, hd), dtype),
+        "v": jnp.zeros((n_attn, batch, cache_len, cfg.n_kv_heads, hd), dtype),
         "pos": jnp.zeros((batch,), jnp.int32),
         "slot_pos": jnp.full((batch, cache_len), -1, jnp.int32),
     }
@@ -92,28 +267,11 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
     B, S = tokens.shape
     window = cfg.sliding_window or 0
     clen = cache_len or (min(S, window) if window else S)
-    x = L.embed(params["emb"], tokens)
-    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    G, A = _n_groups(cfg), cfg.attn_every
-
-    def mamba_body(x, lp):
-        x, h, conv = mamba_forward(cfg, lp, x)
-        return x, (h, conv.astype(dtype))
-
-    hs, convs, ks, vs = [], [], [], []
-    sp = params["shared"]
-    for g in range(G):
-        x, (h, conv) = L.layer_scan(mamba_body, x,
-                                    _group_slice(params["layers"], g, A))
-        hs.append(h); convs.append(conv)
-        hnorm = L.apply_norm(cfg, sp["norm1"], x)
-        o, k, v = L.attention_forward(cfg, sp["attn"], hnorm,
-                                      positions=positions, return_kv=True)
-        x = x + o
-        hnorm = L.apply_norm(cfg, sp["norm2"], x)
-        x = x + L.ffn_forward(cfg, sp["ffn"], hnorm)
-        ks.append(k.astype(dtype)); vs.append(v.astype(dtype))
-
+    if cfg.layer_types:
+        x, (hs, convs, ks, vs) = _pattern_stack(
+            cfg, params, _embed(cfg, params, tokens), dtype)
+    else:
+        x, (hs, convs, ks, vs) = _zamba_prefill(cfg, params, tokens, dtype)
     k_all, v_all, spos = L.fit_cache(jnp.stack(ks), jnp.stack(vs), S, clen,
                                      window, B)
     cache = {
@@ -123,25 +281,66 @@ def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array, *,
         "pos": jnp.full((B,), S, jnp.int32),
         "slot_pos": spos,
     }
+    if cfg.layer_types:
+        return _logits(cfg, params, x[:, -1]), cache
     x = L.rms_norm(x, params["final_norm"]["w"])
     logits = L.unembed(params["emb"], x[:, -1:])
     return logits[:, 0], cache
 
 
+def _zamba_prefill(cfg: ModelConfig, params: Params, tokens: jax.Array,
+                   dtype):
+    B, S = tokens.shape
+    x = L.embed(params["emb"], tokens)
+    positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+    G, A = _n_groups(cfg), cfg.attn_every
+
+    def mamba_body(x, lp):
+        with jax.named_scope("mamba"):
+            x, h, conv = mamba_forward(cfg, lp, x)
+        return x, (h, conv.astype(dtype))
+
+    hs, convs, ks, vs = [], [], [], []
+    sp = params["shared"]
+    for g in range(G):
+        x, (h, conv) = L.layer_scan(mamba_body, x,
+                                    _group_slice(params["layers"], g, A))
+        hs.append(h)
+        convs.append(conv)
+        with jax.named_scope("attention"):
+            hnorm = L.apply_norm(cfg, sp["norm1"], x)
+            o, k, v = L.attention_forward(cfg, sp["attn"], hnorm,
+                                          positions=positions, return_kv=True)
+            x = x + o
+        with jax.named_scope("mlp"):
+            hnorm = L.apply_norm(cfg, sp["norm2"], x)
+            x = x + L.ffn_forward(cfg, sp["ffn"], hnorm)
+        ks.append(k.astype(dtype))
+        vs.append(v.astype(dtype))
+    return x, (hs, convs, ks, vs)
+
+
 def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                 cache: Dict[str, jax.Array]):
     B = tokens.shape[0]
-    x = L.embed(params["emb"], tokens)
     pos = cache["pos"]
     Sc = cache["k"].shape[2]
     slot = pos % Sc if cfg.sliding_window > 0 else pos
     slot_pos = cache["slot_pos"].at[jnp.arange(B), slot].set(pos)
+    if cfg.layer_types:
+        x, ssm, conv, k, v = _pattern_decode(
+            cfg, params, _embed(cfg, params, tokens), cache, pos, slot_pos)
+        return _logits(cfg, params, x)[:, 0], dict(
+            cache, ssm=ssm, conv=conv, k=k, v=v, pos=pos + 1,
+            slot_pos=slot_pos)
+    x = L.embed(params["emb"], tokens)
     G, A = _n_groups(cfg), cfg.attn_every
     sp = params["shared"]
 
     def mamba_body(x, inp):
         lp, h, conv = inp
-        x, h, conv = mamba_decode(cfg, lp, x, h, conv)
+        with jax.named_scope("mamba"):
+            x, h, conv = mamba_decode(cfg, lp, x, h, conv)
         return x, (h, conv)
 
     hs, convs, ks, vs = [], [], [], []
@@ -150,14 +349,19 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
                cache["ssm"][g * A:(g + 1) * A],
                cache["conv"][g * A:(g + 1) * A])
         x, (h, conv) = L.layer_scan(mamba_body, x, grp)
-        hs.append(h); convs.append(conv)
-        hnorm = L.apply_norm(cfg, sp["norm1"], x)
-        o, kc, vc = L.attention_decode(cfg, sp["attn"], hnorm, cache["k"][g],
-                                       cache["v"][g], pos, slot_pos)
-        x = x + o
-        hnorm = L.apply_norm(cfg, sp["norm2"], x)
-        x = x + L.ffn_forward(cfg, sp["ffn"], hnorm)
-        ks.append(kc); vs.append(vc)
+        hs.append(h)
+        convs.append(conv)
+        with jax.named_scope("attention"):
+            hnorm = L.apply_norm(cfg, sp["norm1"], x)
+            o, kc, vc = L.attention_decode(cfg, sp["attn"], hnorm,
+                                           cache["k"][g], cache["v"][g], pos,
+                                           slot_pos)
+            x = x + o
+        with jax.named_scope("mlp"):
+            hnorm = L.apply_norm(cfg, sp["norm2"], x)
+            x = x + L.ffn_forward(cfg, sp["ffn"], hnorm)
+        ks.append(kc)
+        vs.append(vc)
 
     x = L.rms_norm(x, params["final_norm"]["w"])
     logits = L.unembed(params["emb"], x)[:, 0]

@@ -139,7 +139,8 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
     """Full-sequence (self or cross) attention.
 
     x (B,S,d). kv_x: source of K/V for cross-attention (B,T,d); None = self.
-    positions: absolute positions (B,S) for RoPE; default arange.
+    positions: absolute positions (B,S) for RoPE; default arange. No
+    rotation where ``use_rope`` is off or ``cfg.rope_theta`` is 0 (NoPE).
     prefix_len: number of leading tokens (e.g. vision tokens) that every
     query may attend to bidirectionally (VLM prefix attention).
     return_kv: also return the (roped) K and V, e.g. for cache building.
@@ -155,7 +156,7 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
     q = (x @ p["wq"]).reshape(B, S, H, hd)
     k = (src @ p["wk"]).reshape(B, src.shape[1], Hkv, hd)
     v = (src @ p["wv"]).reshape(B, src.shape[1], Hkv, hd)
-    if use_rope and kv_x is None:
+    if use_rope and cfg.rope_theta and kv_x is None:
         if positions is None:
             positions = jnp.broadcast_to(
                 past_len + jnp.arange(S)[None, :], (B, S))
@@ -168,18 +169,29 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
         v = jnp.concatenate([past_kv[1].astype(v.dtype), v], axis=1)
     T = k.shape[1]
     if kv_x is None and S >= 2048:
-        # long sequences: chunked online-softmax path (§Perf B1) — avoids
-        # materializing the (S, T) score matrix
-        out = _flash_attention_ref(q, k, v, causal=causal,
-                                   window=cfg.sliding_window,
-                                   prefix_len=prefix_len,
-                                   n_heads=H, n_kv=Hkv,
-                                   q_offset=past_len)
+        # long sequences: flash attention, which never materializes the
+        # (S, T) score matrix. The Pallas kernel on TPU where it applies (a
+        # whole causal prompt), the jnp online-softmax form elsewhere.
+        from repro.kernels import ops as _kops
+        if causal and past_kv is None and not cfg.sliding_window and \
+                not prefix_len and _kops.default_backend() == "tpu":
+            o = _kops.flash_prefill(q.transpose(0, 2, 1, 3),
+                                    k.transpose(0, 2, 1, 3),
+                                    v.transpose(0, 2, 1, 3),
+                                    scale=cfg.attn_scale)
+            out = o.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+        else:
+            out = _flash_attention_ref(q, k, v, causal=causal,
+                                       window=cfg.sliding_window,
+                                       prefix_len=prefix_len,
+                                       n_heads=H, n_kv=Hkv,
+                                       scale=cfg.attn_scale,
+                                       q_offset=past_len)
         out = out @ p["wo"]
         if return_kv:
             return out, new_k, new_v
         return out
-    scores = _gqa_scores_full(q, k, H, Hkv) / math.sqrt(hd)   # (B,H,S,T)
+    scores = _gqa_scores_full(q, k, H, Hkv) * cfg.attn_scale   # (B,H,S,T)
     if causal and kv_x is None:
         qi = past_len + jnp.arange(S)[:, None]
         ki = jnp.arange(T)[None, :]
@@ -243,6 +255,7 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     x (B,1,d); k_cache/v_cache (B,S,Hkv,D); pos (B,) absolute position of the
     new token; slot_pos (B,S) absolute position held by each slot (-1 empty,
     already including this step's write). Returns (out (B,1,d), k, v).
+    No rotation where ``use_rope`` is off or ``cfg.rope_theta`` is 0.
     """
     B = x.shape[0]
     hd = cfg.resolved_head_dim
@@ -251,22 +264,26 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     q = (x @ p["wq"]).reshape(B, 1, H, hd)
     k = (x @ p["wk"]).reshape(B, 1, Hkv, hd)
     v = (x @ p["wv"]).reshape(B, 1, Hkv, hd)
-    if use_rope:
+    if use_rope and cfg.rope_theta:
         cos, sin = rope_angles(pos[:, None], hd, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     slot = pos % S if cfg.sliding_window > 0 else pos
-    bidx = jnp.arange(B)
-    k_cache = k_cache.at[bidx, slot].set(k[:, 0])
-    v_cache = v_cache.at[bidx, slot].set(v[:, 0])
+    # the new K/V go in by a select over the cache, not a scatter:
+    # an undonated step writes the whole cache anew either way, and the
+    # select keeps the layout the cache is stored in, where a scatter made
+    # XLA copy the cache to another layout and back
+    here = (jnp.arange(S)[None, :] == slot[:, None])[:, :, None, None]
+    k_cache = jnp.where(here, k, k_cache)
+    v_cache = jnp.where(here, v, v_cache)
     # scores over the whole cache, masked by slot validity. f32 via the
-    # dot's accumulator (preferred_element_type), NOT by casting inputs —
+    # dot's accumulator (preferred_element_type), NOT by casting inputs:
     # an input cast materializes an f32 copy of the whole K cache per
-    # layer (§Perf C2: that copy was ~all of the decode memory term).
+    # layer, as large as the decode step's other memory traffic.
     group = H // Hkv
     qg = q.reshape(B, Hkv, group, hd)
     s = jnp.einsum("bkgd,bskd->bkgs", qg, k_cache,
-                   preferred_element_type=jnp.float32) / math.sqrt(hd)
+                   preferred_element_type=jnp.float32) * cfg.attn_scale
     valid = slot_pos >= 0
     if cfg.sliding_window > 0:
         valid &= slot_pos[:, :] > (pos[:, None] - cfg.sliding_window)
@@ -295,12 +312,13 @@ def cross_attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
 
 def _flash_attention_ref(q, k, v, *, causal: bool, window: int,
                          prefix_len: int, n_heads: int, n_kv: int,
-                         block: int = 1024, q_offset: int = 0) -> jax.Array:
+                         scale: float, block: int = 1024,
+                         q_offset: int = 0) -> jax.Array:
     """Chunked online-softmax attention (pure JAX; the lax.scan analogue of
     kernels/flash_prefill). Never materializes the (S, T) score matrix in
-    HBM — §Perf B1: for yi-34b train_4k the full materialization made the
-    memory roofline term 9x larger than the flash form. q (B,S,H,D);
-    k/v (B,T,Hkv,D) (already roped). Returns (B,S,H*D)."""
+    HBM: at long sequences that matrix would be most of the memory
+    traffic. q (B,S,H,D); k/v (B,T,Hkv,D) (already roped); scores are
+    multiplied by ``scale``. Returns (B,S,H*D)."""
     B, S, H, D = q.shape
     T = k.shape[1]
     g = n_heads // n_kv
@@ -318,7 +336,7 @@ def _flash_attention_ref(q, k, v, *, causal: bool, window: int,
         ks = jax.lax.dynamic_slice_in_dim(k, j * block, block, 1)
         vs = jax.lax.dynamic_slice_in_dim(v, j * block, block, 1)
         s = jnp.einsum("bkgsd,btkd->bkgst", qg, ks,
-                       preferred_element_type=jnp.float32) / math.sqrt(D)
+                       preferred_element_type=jnp.float32) * scale
         kpos = j * block + jnp.arange(block)
         mask = kpos[None, :] < T
         if causal:

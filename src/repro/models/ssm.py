@@ -157,10 +157,19 @@ def _causal_conv(xBC: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     return jax.nn.silu(out + b)
 
 
+def _residual(cfg: ModelConfig, out: jax.Array) -> jax.Array:
+    """The block's output as added to the residual stream: scaled by the
+    muP ``residual_multiplier`` where the config has one."""
+    if cfg.residual_multiplier != 1.0:
+        return out * cfg.residual_multiplier
+    return out
+
+
 def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array,
                   h0: Optional[jax.Array] = None,
                   conv0: Optional[jax.Array] = None):
-    """Full-sequence Mamba2 block. x (b,s,d) -> (y, final_ssm_state, conv_state)."""
+    """Full-sequence Mamba2 block (pre-norm, mixer, residual add).
+    x (b,s,d) -> (y, final_ssm_state, conv_state)."""
     b, s, d = x.shape
     di, N, H = cfg.d_inner, cfg.ssm.state_dim, cfg.n_ssm_heads
     P = cfg.ssm.head_dim
@@ -185,7 +194,7 @@ def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     y = y + p["D"][None, None, :, None].astype(y.dtype) * xs
     y = y.reshape(b, s, di)
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"])
-    out = y @ p["w_out"]
+    out = _residual(cfg, y @ p["w_out"])
     conv_state = xBC[:, -(cfg.ssm.conv_width - 1):, :]
     return x + out, h_final, conv_state
 
@@ -212,6 +221,6 @@ def mamba_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     y = y + p["D"][None, :, None].astype(y.dtype) * xs
     y = y.reshape(b, 1, di)
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"])
-    out = y @ p["w_out"]
+    out = _residual(cfg, y @ p["w_out"])
     new_conv = window[:, 1:, :]
     return x + out, h_new, new_conv

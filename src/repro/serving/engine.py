@@ -65,7 +65,10 @@ class StepStats:
 @dataclass
 class _Slot:
     request: Optional[Request] = None
-    token: Optional[jax.Array] = None   # next input token (1,)
+    # next input token: row ``row`` of ``token``, the prefill's argmax (1,)
+    # or the last decode step's argmax over every slot (max_slots,)
+    token: Optional[jax.Array] = None
+    row: int = 0
 
     @property
     def active(self) -> bool:
@@ -312,7 +315,7 @@ class Engine:
             # 2. one decode iteration over the whole slot pool
             with span("engine.stack", obs, iid):
                 tokens = jnp.stack([
-                    s.token[0] if s.active else jnp.zeros((), jnp.int32)
+                    s.token[s.row] if s.active else jnp.zeros((), jnp.int32)
                     for s in self.slots])[:, None]
             with span("engine.decode", obs, iid):
                 logits, self.pool = self._decode(self.params, tokens,
@@ -329,8 +332,12 @@ class Engine:
             self._last_step_t = t_end
             stats.itl = itl
 
-            # 3. bookkeeping: ITL samples, finishes
+            # 3. bookkeeping: ITL samples, finishes. The positions come to
+            # the host in one read, and a continuing slot keeps its row of
+            # next_tok, so the host's work here issues no device op per
+            # active slot and a token gap does not grow with their number
             with span("engine.retire", obs, iid):
+                pos = np.asarray(self.pool["pos"])
                 for i in active_idx:
                     s = self.slots[i]
                     req = s.request
@@ -340,13 +347,13 @@ class Engine:
                     if req.first_token_time is None:
                         req.first_token_time = t_end
                     if req.tokens_generated >= req.output_len or \
-                            int(self.pool["pos"][i]) >= self.max_len - 1:
+                            pos[i] >= self.max_len - 1:
                         req.state = RequestState.FINISHED
                         req.finish_time = t_end
                         stats.finished.append(req)
                         self.slots[i] = _Slot()
                     else:
-                        s.token = next_tok[i:i + 1]
+                        s.token, s.row = next_tok, i
 
                 self._window.append((t_end, stats.new_tokens))
                 stats.throughput = self.throughput()

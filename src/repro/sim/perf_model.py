@@ -104,10 +104,8 @@ class PerfModel:
         hd = cfg.resolved_head_dim
         if cfg.arch_type == "ssm":
             return 0.0  # O(1) state, amortized to ~0 per token
-        n_attn_layers = cfg.n_layers
-        if cfg.arch_type == "hybrid":
-            n_attn_layers = cfg.n_layers // max(cfg.attn_every, 1)
-        return 2 * n_attn_layers * cfg.n_kv_heads * hd * BYTES_PER_PARAM
+        return 2 * cfg.n_attention_layers * cfg.n_kv_heads * hd * \
+            BYTES_PER_PARAM
 
     def kv_bytes_per_token(self) -> float:
         return self._kv_per_tok

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.ssd_scan import ssd_scan
 from repro.models import Model
 
@@ -64,6 +65,44 @@ def test_ssd_scan_compiles_at_real_widths(one_chip, arch):
                                         chunk=cfg.ssm.chunk_size)
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_prefill_compiles_at_granite_widths(one_chip):
+    """granite-4.0-h-micro's attention prefill: GQA 32/8 heads of 64, the
+    muP scale; the custom call is named for the trace's reader."""
+    cfg = get_config("granite-4.0-h-micro")
+    s, hd = 2048, cfg.resolved_head_dim
+    f32 = jnp.float32
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((1, cfg.n_heads, s, hd), f32),
+        jax.ShapeDtypeStruct((1, cfg.n_kv_heads, s, hd), f32),
+        jax.ShapeDtypeStruct((1, cfg.n_kv_heads, s, hd), f32)))
+    text = jax.jit(
+        lambda q, k, v: flash_prefill(q, k, v, scale=cfg.attn_scale)
+    ).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%flash_prefill" in text
+
+
+def test_granite_decode_step_fits_one_chip(one_chip):
+    """The benchmark's cut of granite-4.0-h-micro (one period of ten
+    layers, float32) with its 8 x 32768-position pool: the undonated decode
+    step fits one chip, with room for what the window holds besides."""
+    cfg = get_config("granite-4.0-h-micro")
+    cfg = cfg.with_(n_layers=10, layer_types=cfg.layer_types[:10])
+    model = Model(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), dtype=jnp.float32))
+    cache = jax.eval_shape(lambda: model.init_cache(8, 32768,
+                                                    dtype=jnp.float32))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    compiled = jax.jit(model.decode_step).lower(
+        *_on(one_chip, (params, tokens, cache))).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert mem.argument_size_in_bytes > 4.8e9   # 3.81 GB weights + pool
+    assert total < 0.5 * V5E_HBM_BYTES, mem
 
 
 def test_mamba2_decode_step_fits_one_chip(one_chip):

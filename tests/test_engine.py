@@ -106,3 +106,42 @@ def test_engine_prefill_matches_eager_model_prefill(family):
         np.testing.assert_allclose(np.asarray(cache[k]),
                                    np.asarray(want_cache[k]),
                                    rtol=1e-5, atol=1e-5, err_msg=k)
+
+
+def test_each_slot_decodes_its_own_greedy_token(engine_cfg):
+    """Every active slot's next decode input is the argmax of its own row of
+    the step before, whichever slots are busy and whoever left."""
+    eng = Engine(engine_cfg, max_slots=4, max_len=96, dtype=jnp.float32)
+    seen = []
+    decode = eng._decode
+
+    def recording(params, tokens, pool):
+        slots = [s.request for s in eng.slots]
+        logits, pool = decode(params, tokens, pool)
+        seen.append((slots, np.asarray(tokens)[:, 0], np.asarray(logits)))
+        return logits, pool
+
+    eng._decode = recording
+    for i in range(5):
+        eng.submit(make_interactive(8 + 3 * i, 4 + 5 * i))
+    _drain(eng, [])
+    checked = 0
+    for (before, _, logits), (after, toks, _) in zip(seen, seen[1:]):
+        for j, (a, b) in enumerate(zip(before, after)):
+            if a is not None and a is b:
+                assert toks[j] == logits[j].argmax(), (j, a.req_id)
+                checked += 1
+    assert checked > 20
+
+
+def test_request_stops_where_the_pool_ends(engine_cfg):
+    """A request that asks for more tokens than its slot holds finishes
+    when its cache reaches ``max_len``."""
+    eng = Engine(engine_cfg, max_slots=2, max_len=32, dtype=jnp.float32)
+    long, short = make_interactive(20, 100), make_interactive(8, 3)
+    eng.submit(long)
+    eng.submit(short)
+    _drain(eng, [long, short])
+    assert long.state == short.state == RequestState.FINISHED
+    assert short.tokens_generated == 3
+    assert long.tokens_generated == 32 - 1 - 20 + 1

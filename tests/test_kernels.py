@@ -76,6 +76,22 @@ def test_flash_prefill_sweep(dtype, B, H, Hkv, S, D, bq, bk):
                                np.asarray(out_r, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("S", [512, 300])
+def test_flash_prefill_mup_scale_gqa(S):
+    """granite-4.0-h-micro's attention: GQA 32/8 heads of 64, scores times
+    the muP attention multiplier 1/64 in place of 1/sqrt(64); a length off
+    the block size is padded and the padding dropped."""
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (1, 32, S, 64))
+    k = jax.random.normal(ks[1], (1, 8, S, 64))
+    v = jax.random.normal(ks[2], (1, 8, S, 64))
+    out_k = ops.flash_prefill(q, k, v, scale=1 / 64, backend="interpret")
+    out_r = ref.flash_prefill_ref(q, k, v, scale=1 / 64)
+    np.testing.assert_allclose(out_k, out_r, atol=2e-4, rtol=2e-4)
+    plain = ref.flash_prefill_ref(q, k, v)
+    assert float(jnp.max(jnp.abs(plain - out_r))) > 1e-2
+
+
 def test_flash_prefill_noncausal():
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (1, 2, 128, 128))
