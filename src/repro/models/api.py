@@ -7,6 +7,7 @@
   prefill(params, batch)           -> (last_logits, cache)
   decode_step(params, tokens, cache)-> (logits, cache)
   init_cache(batch, cache_len)     -> zeroed cache pytree
+  serving_params(params, bf16_dots)-> params as the serving programs take them
   example_batch(batch, seq, key)   -> random batch with the right modalities
 
 ``batch`` is a dict: always ``tokens (B,S) int32``; plus ``frames`` for audio
@@ -91,6 +92,16 @@ class Model:
 
     def decode_step(self, params: Params, tokens: jax.Array, cache):
         return self._m.decode_step(self.cfg, params, tokens, cache)
+
+    def serving_params(self, params: Params, bf16_dots: bool) -> Params:
+        """``params`` as the serving programs take them. With ``bf16_dots``
+        (the backend computes a float32 dot at default precision as one
+        bfloat16 pass) the family rounds the weights it feeds only to such
+        dots to bfloat16 here, once, rather than inside every call. Without
+        it, or for a family that names no such weights, ``params`` itself."""
+        prepare = getattr(self._m, "serving_params", None) if bf16_dots \
+            else None
+        return prepare(self.cfg, params) if prepare else params
 
     # ------------------------------------------------------------ inputs
     def example_batch(self, batch: int, seq: int, key=None,

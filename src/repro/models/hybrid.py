@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.models import ssm
 from repro.models.ssm import init_mamba_layer, mamba_decode, mamba_forward
 
 Params = Dict[str, Any]
@@ -65,6 +66,29 @@ def _rows(stacked: Params, lo: int, n: int) -> Params:
 
 def _row(stacked: Params, i: int) -> Params:
     return jax.tree.map(lambda a: a[i], stacked)
+
+
+def _run_weights(params: Params, kind: str, j: int, first: int,
+                 n: int) -> Params:
+    """One run's weights, sliced from the stacks by kind: a Mamba2 run's
+    mixers, MLPs and MLP norms stacked over its layers; an attention
+    layer's mixer, input norm, MLP and MLP norm."""
+    norm = params["mlp_norm"]["w"]
+    if kind == "mamba":
+        return {"mamba": _rows(params["mamba"], j, n),
+                "mlp": _rows(params["mlp"], first, n),
+                "mlp_norm": norm[first:first + n]}
+    return {"attn": _row(params["attn"], j),
+            "attn_norm": params["attn_norm"]["w"][j],
+            "mlp": _row(params["mlp"], first), "mlp_norm": norm[first]}
+
+
+def _runs(cfg: ModelConfig, params: Params):
+    """The runs of the layer pattern (``_segments``), each with its
+    weights: as ``serving_params`` sliced them once, or sliced here."""
+    segs = _segments(cfg)
+    runs = params.get("runs") or [_run_weights(params, *s) for s in segs]
+    return list(zip(segs, runs))
 
 
 def init_params(cfg: ModelConfig, key, dtype=None) -> Params:
@@ -163,22 +187,18 @@ def _pattern_stack(cfg: ModelConfig, params: Params, x: jax.Array, dtype):
             x, h, conv = mamba_forward(cfg, mp, x)
         return _mlp(cfg, fp, nw, x), (h, conv.astype(dtype))
 
-    for kind, j, first, n in _segments(cfg):
-        mlp = (_rows(params["mlp"], first, n),
-               params["mlp_norm"]["w"][first:first + n])
+    for (kind, *_), w in _runs(cfg, params):
         if kind == "mamba":
             x, (h, conv) = L.layer_scan(
-                mamba_mlp, x, (_rows(params["mamba"], j, n),) + mlp)
+                mamba_mlp, x, (w["mamba"], w["mlp"], w["mlp_norm"]))
             hs.append(h)
             convs.append(conv)
             continue
         with jax.named_scope("attention"):
-            h = L.rms_norm(x, params["attn_norm"]["w"][j])
-            o, k, v = L.attention_forward(cfg, _row(params["attn"], j), h,
-                                          return_kv=True)
+            h = L.rms_norm(x, w["attn_norm"])
+            o, k, v = L.attention_forward(cfg, w["attn"], h, return_kv=True)
             x = x + cfg.residual_multiplier * o
-        x = _mlp(cfg, _row(params["mlp"], first),
-                 params["mlp_norm"]["w"][first], x)
+        x = _mlp(cfg, w["mlp"], w["mlp_norm"], x)
         ks.append(k.astype(dtype))
         vs.append(v.astype(dtype))
     return x, (hs, convs, ks, vs)
@@ -198,27 +218,37 @@ def _pattern_decode(cfg: ModelConfig, params: Params, x: jax.Array,
             x, h, conv = mamba_decode(cfg, mp, x, h, conv)
         return _mlp(cfg, fp, nw, x), (h, conv)
 
-    for kind, j, first, n in _segments(cfg):
+    for (kind, j, _, n), w in _runs(cfg, params):
         if kind == "mamba":
             x, (h, conv) = L.layer_scan(mamba_mlp, x, (
-                _rows(params["mamba"], j, n), _rows(params["mlp"], first, n),
-                params["mlp_norm"]["w"][first:first + n],
+                w["mamba"], w["mlp"], w["mlp_norm"],
                 cache["ssm"][j:j + n], cache["conv"][j:j + n]))
             hs.append(h)
             convs.append(conv)
             continue
         with jax.named_scope("attention"):
-            h = L.rms_norm(x, params["attn_norm"]["w"][j])
-            o, kc, vc = L.attention_decode(cfg, _row(params["attn"], j), h,
+            h = L.rms_norm(x, w["attn_norm"])
+            o, kc, vc = L.attention_decode(cfg, w["attn"], h,
                                            k_all[j], v_all[j], pos, slot_pos)
             k_all, v_all = k_all.at[j].set(kc), v_all.at[j].set(vc)
             x = x + cfg.residual_multiplier * o
-        x = _mlp(cfg, _row(params["mlp"], first),
-                 params["mlp_norm"]["w"][first], x)
+        x = _mlp(cfg, w["mlp"], w["mlp_norm"], x)
     return x, jnp.concatenate(hs), jnp.concatenate(convs), k_all, v_all
 
 
 # ------------------------------------------------------------- API
+
+
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``Model.serving_params``: the Mamba2, attention, MLP and untied-head
+    weights rounded to bfloat16; in the layer-pattern form each run's
+    weights are also sliced from the stacks now, once, since a run's slice
+    of a stack is otherwise a copy the program makes in every call."""
+    params = L.round_dot_weights(params, ssm.DOT_WEIGHTS + L.DOT_WEIGHTS)
+    if not cfg.layer_types:
+        return params
+    return {"emb": params["emb"], "final_norm": params["final_norm"],
+            "runs": [_run_weights(params, *s) for s in _segments(cfg)]}
 
 
 def forward(cfg: ModelConfig, params: Params, tokens: jax.Array, *,

@@ -40,6 +40,35 @@ def _dense_init(key, shape, dtype, scale: Optional[float] = None):
     return (jax.random.normal(key, shape) * scale).astype(dtype)
 
 
+def dense(x: jax.Array, w: jax.Array) -> jax.Array:
+    """``x @ w`` for a weight that only feeds this product. A bfloat16 ``w``
+    under a float32 ``x`` is a weight rounded once for a backend whose
+    default-precision float32 dot is one bfloat16 pass (``serving_params``
+    of each family): ``x`` is rounded as that dot rounds it and the product
+    accumulates and returns in float32. A plain ``x @ w`` would promote
+    ``w`` back to a float32 copy."""
+    if w.dtype == jnp.bfloat16 and x.dtype == jnp.float32:
+        return jnp.matmul(x.astype(w.dtype), w,
+                          preferred_element_type=jnp.float32)
+    return x @ w
+
+
+# the weights this module feeds only to ``dense``: attention, FFN, untied head
+DOT_WEIGHTS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head")
+
+
+def round_dot_weights(params: Params, names) -> Params:
+    """``params`` with every float32 leaf under a key in ``names`` rounded
+    to bfloat16; the rest are the same arrays."""
+    def walk(p):
+        if not isinstance(p, dict):
+            return p
+        return {k: v.astype(jnp.bfloat16)
+                if k in names and getattr(v, "dtype", None) == jnp.float32
+                else walk(v) for k, v in p.items()}
+    return walk(params)
+
+
 def rms_norm(x: jax.Array, w: Optional[jax.Array], eps: float = 1e-5) -> jax.Array:
     dt = x.dtype
     xf = x.astype(jnp.float32)
@@ -153,9 +182,9 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
     src = x if kv_x is None else kv_x
     past_len = past_kv[0].shape[1] if past_kv is not None else 0
-    q = (x @ p["wq"]).reshape(B, S, H, hd)
-    k = (src @ p["wk"]).reshape(B, src.shape[1], Hkv, hd)
-    v = (src @ p["wv"]).reshape(B, src.shape[1], Hkv, hd)
+    q = dense(x, p["wq"]).reshape(B, S, H, hd)
+    k = dense(src, p["wk"]).reshape(B, src.shape[1], Hkv, hd)
+    v = dense(src, p["wv"]).reshape(B, src.shape[1], Hkv, hd)
     if use_rope and cfg.rope_theta and kv_x is None:
         if positions is None:
             positions = jnp.broadcast_to(
@@ -187,7 +216,7 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
                                        n_heads=H, n_kv=Hkv,
                                        scale=cfg.attn_scale,
                                        q_offset=past_len)
-        out = out @ p["wo"]
+        out = dense(out, p["wo"])
         if return_kv:
             return out, new_k, new_v
         return out
@@ -205,7 +234,7 @@ def attention_forward(cfg: ModelConfig, p: Params, x: jax.Array, *,
     group = H // Hkv
     wv = w.reshape(B, Hkv, group, S, T)
     o = jnp.einsum("bkgst,btkd->bskgd", wv, v).reshape(B, S, H * hd)
-    out = o @ p["wo"]
+    out = dense(o, p["wo"])
     if return_kv:
         return out, new_k, new_v   # new tokens only (past excluded)
     return out
@@ -261,9 +290,9 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     hd = cfg.resolved_head_dim
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
     S = k_cache.shape[1]
-    q = (x @ p["wq"]).reshape(B, 1, H, hd)
-    k = (x @ p["wk"]).reshape(B, 1, Hkv, hd)
-    v = (x @ p["wv"]).reshape(B, 1, Hkv, hd)
+    q = dense(x, p["wq"]).reshape(B, 1, H, hd)
+    k = dense(x, p["wk"]).reshape(B, 1, Hkv, hd)
+    v = dense(x, p["wv"]).reshape(B, 1, Hkv, hd)
     if use_rope and cfg.rope_theta:
         cos, sin = rope_angles(pos[:, None], hd, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
@@ -291,7 +320,7 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     o = jnp.einsum("bkgs,bskd->bkgd", w, v_cache).reshape(B, 1, H * hd)
-    return o @ p["wo"], k_cache, v_cache
+    return dense(o, p["wo"]), k_cache, v_cache
 
 
 def cross_attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -300,14 +329,14 @@ def cross_attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     B = x.shape[0]
     hd = cfg.resolved_head_dim
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
-    q = (x @ p["wq"]).reshape(B, 1, H, hd)
+    q = dense(x, p["wq"]).reshape(B, 1, H, hd)
     group = H // Hkv
     qg = q.reshape(B, Hkv, group, hd)
     s = jnp.einsum("bkgd,btkd->bkgt", qg.astype(jnp.float32),
                    k_cache.astype(jnp.float32)) / math.sqrt(hd)
     w = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     o = jnp.einsum("bkgt,btkd->bkgd", w, v_cache).reshape(B, 1, H * hd)
-    return o @ p["wo"]
+    return dense(o, p["wo"])
 
 
 def _flash_attention_ref(q, k, v, *, causal: bool, window: int,
@@ -381,8 +410,9 @@ def init_ffn(cfg: ModelConfig, key, dtype, d_ff: Optional[int] = None,
 
 def ffn_forward(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
     if cfg.ffn == "swiglu":
-        return (jax.nn.silu(x @ p["w_gate"]) * (x @ p["w_up"])) @ p["w_down"]
-    return jax.nn.gelu(x @ p["w_up"]) @ p["w_down"]
+        return dense(jax.nn.silu(dense(x, p["w_gate"])) * dense(x, p["w_up"]),
+                     p["w_down"])
+    return dense(jax.nn.gelu(dense(x, p["w_up"])), p["w_down"])
 
 
 # ---------------------------------------------------------------- embeddings
@@ -402,5 +432,5 @@ def embed(p: Params, tokens: jax.Array) -> jax.Array:
 
 def unembed(p: Params, x: jax.Array) -> jax.Array:
     if "head" in p:
-        return x @ p["head"]
+        return dense(x, p["head"])
     return x @ p["tok"].T

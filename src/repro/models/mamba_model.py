@@ -8,7 +8,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.models.ssm import init_mamba_layer, mamba_decode, mamba_forward
+from repro.models.ssm import (DOT_WEIGHTS, init_mamba_layer, mamba_decode,
+                              mamba_forward)
 
 Params = Dict[str, Any]
 
@@ -82,3 +83,9 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     x = L.rms_norm(x, params["final_norm"]["w"])
     logits = L.unembed(params["emb"], x)[:, 0]
     return logits, dict(cache, ssm=hs, conv=convs, pos=cache["pos"] + 1)
+
+
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``Model.serving_params``: the in and out projections (and an untied
+    head) rounded to bfloat16."""
+    return L.round_dot_weights(params, DOT_WEIGHTS + ("head",))

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import layers as L
 from repro.models.layers import _dense_init, rms_norm
 
 Params = Dict[str, Any]
@@ -119,6 +120,10 @@ def ssd_decode_step(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
 # ------------------------------------------------------------- Mamba2 block
 
 
+# the block's weights fed only to ``layers.dense``
+DOT_WEIGHTS = ("w_in", "w_out")
+
+
 def init_mamba_layer(cfg: ModelConfig, key, dtype) -> Params:
     d = cfg.d_model
     di = cfg.d_inner
@@ -174,7 +179,7 @@ def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     di, N, H = cfg.d_inner, cfg.ssm.state_dim, cfg.n_ssm_heads
     P = cfg.ssm.head_dim
     hid = rms_norm(x, p["rms_w"])
-    proj = hid @ p["w_in"]
+    proj = L.dense(hid, p["w_in"])
     z, xBC, dt_raw = _split_proj(cfg, proj)
     if conv0 is not None:
         xBC_ext = jnp.concatenate([conv0.astype(xBC.dtype), xBC], axis=1)
@@ -194,7 +199,7 @@ def mamba_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     y = y + p["D"][None, None, :, None].astype(y.dtype) * xs
     y = y.reshape(b, s, di)
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"])
-    out = _residual(cfg, y @ p["w_out"])
+    out = _residual(cfg, L.dense(y, p["w_out"]))
     conv_state = xBC[:, -(cfg.ssm.conv_width - 1):, :]
     return x + out, h_final, conv_state
 
@@ -206,7 +211,7 @@ def mamba_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     di, N, H = cfg.d_inner, cfg.ssm.state_dim, cfg.n_ssm_heads
     P = cfg.ssm.head_dim
     hid = rms_norm(x, p["rms_w"])
-    proj = hid @ p["w_in"]
+    proj = L.dense(hid, p["w_in"])
     z, xBC, dt_raw = _split_proj(cfg, proj)
     window = jnp.concatenate([conv_state.astype(xBC.dtype), xBC], axis=1)
     w = p["conv_w"]
@@ -221,6 +226,6 @@ def mamba_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     y = y + p["D"][None, :, None].astype(y.dtype) * xs
     y = y.reshape(b, 1, di)
     y = rms_norm(y * jax.nn.silu(z), p["norm_w"])
-    out = _residual(cfg, y @ p["w_out"])
+    out = _residual(cfg, L.dense(y, p["w_out"]))
     new_conv = window[:, 1:, :]
     return x + out, h_new, new_conv

@@ -200,3 +200,13 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     logits = L.unembed(params["emb"], x)[:, 0]
     new_cache = dict(cache, k=ks, v=vs, pos=pos + 1, slot_pos=slot_pos)
     return logits, new_cache
+
+
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``Model.serving_params``: a dense stack's attention and FFN weights
+    (and an untied head) rounded to bfloat16. The MoE and vision-language
+    stacks keep theirs: no cell serves them, and MoE experts are not fed
+    through ``layers.dense``."""
+    if cfg.arch_type != "dense":
+        return params
+    return L.round_dot_weights(params, L.DOT_WEIGHTS)

@@ -51,6 +51,34 @@ def prefill_program(cfg: ModelConfig, dtype) -> Callable:
     return jax.jit(engine_prefill)
 
 
+def dot_is_one_bf16_pass(dot: Callable, device=None) -> bool:
+    """Whether ``dot`` (float32 operands, committed to ``device``) computes
+    what rounding both operands to bfloat16 and multiplying exactly
+    computes. The operands are small integers scaled by 1 + 2**-10, a
+    factor bfloat16 rounds away and float32 or TF32 keep; every product and
+    sum of the rounded operands is exact in any order of accumulation."""
+    i = np.arange(128 * 128)
+    ints = [(1 + i[:8 * 128] % 8).reshape(8, 128),
+            (1 + (3 * i + i // 128) % 8).reshape(128, 128)]
+    x, w = (a.astype(np.float32) * np.float32(1 + 2 ** -10) for a in ints)
+    got = np.asarray(dot(jax.device_put(x, device), jax.device_put(w, device)))
+    xr, wr = (a.astype(jnp.bfloat16).astype(np.float64) for a in (x, w))
+    return bool(np.array_equal(got, xr @ wr))
+
+
+@functools.lru_cache(maxsize=None)
+def _dot_probe(device, precision) -> bool:
+    del precision        # a key of the cache: the setting the dot runs under
+    return dot_is_one_bf16_pass(jnp.matmul, device)
+
+
+def default_dot_is_bf16(device) -> bool:
+    """Whether a float32 ``x @ w`` at the default matmul precision runs on
+    ``device`` as one bfloat16 pass (so on the TPU, not on the CPU).
+    Observed once per device and precision setting."""
+    return _dot_probe(device, jax.config.jax_default_matmul_precision)
+
+
 @dataclass
 class StepStats:
     now: float
@@ -76,20 +104,20 @@ class _Slot:
 
 
 class Engine:
-    # flight recorder (repro.obs) handed over by RealCluster when telemetry
-    # is armed, and the serving instance's id its span rows carry; with
-    # None each span site costs a profiler annotation and one branch
-    obs = None
-    instance_id = -1
-
     def __init__(self, cfg: ModelConfig, *, key=None, params=None,
                  max_slots: int = 8, max_len: int = 256,
                  max_batch_size: Optional[int] = None,
                  clock=time.monotonic, dtype=jnp.float32,
                  prefix_cache_entries: int = 0,
                  prefill_chunk: int = 0,
-                 device: Optional[jax.Device] = None):
+                 device: Optional[jax.Device] = None,
+                 obs=None, instance_id: int = -1):
         self.cfg = cfg
+        # flight recorder (repro.obs), which RealCluster also hands over
+        # when telemetry is armed, and the serving instance's id its span
+        # rows carry; with None each span site costs a profiler annotation
+        # and one branch
+        self.obs, self.instance_id = obs, instance_id
         self.model = Model(cfg)
         self.dtype = dtype
         # the params copy and the slot pool live on ``device`` (the first
@@ -100,7 +128,7 @@ class Engine:
         with jax.default_device(self.device):
             params = params if params is not None else \
                 self.model.init(key, dtype=dtype)
-            self.params = jax.device_put(params, self.device)
+            self.params = self._prepare(jax.device_put(params, self.device))
             self.pool = self.model.init_cache(max_slots, max_len, dtype=dtype)
         self.max_slots = max_slots
         self.max_len = max_len
@@ -123,6 +151,22 @@ class Engine:
         self._last_step_t: Optional[float] = None
         self._window: Deque = deque(maxlen=32)   # (t, tokens) samples
         self._rng = np.random.default_rng(0)
+
+    def _prepare(self, params):
+        """The params the decode and prefill programs take: where the
+        device's float32 dot is one bfloat16 pass, the dot-only weights
+        rounded to bfloat16 now, so no program converts them per call. The
+        span's ``arg`` is the MiB so rounded (0 where nothing is)."""
+        prep = functools.partial(self.model.serving_params,
+                                 bf16_dots=default_dot_is_bf16(self.device))
+        rounded = sum(
+            a.size * a.dtype.itemsize for a, b in zip(
+                jax.tree.leaves(params),
+                jax.tree.leaves(jax.eval_shape(prep, params)))
+            if a.dtype != b.dtype)
+        with span("engine.prepare_weights", self.obs, self.instance_id,
+                  arg=round(rounded / 2 ** 20)):
+            return jax.block_until_ready(prep(params))
 
     # ------------------------------------------------------------ metrics
     @property

@@ -64,8 +64,8 @@ class RealInstance:
                              max_batch_size=(local_autoscaler.max_batch_size
                                              if local_autoscaler
                                              else static_batch or max_slots),
-                             dtype=jnp.float32, device=device)
-        self.engine.instance_id = self.id
+                             dtype=jnp.float32, device=device,
+                             instance_id=self.id)
         self._last_stats: Optional[StepStats] = None
         # slow-node health protocol (SimInstance parity): the routing
         # layer reads ``suspected_slow``; a real deployment would EWMA

@@ -7,6 +7,7 @@ that only the worker running this file loads the TPU library. Nothing
 here runs on a device: results and times need the chip itself.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -122,3 +123,31 @@ def test_mamba2_decode_step_fits_one_chip(one_chip):
              + mem.output_size_in_bytes)
     assert mem.argument_size_in_bytes > 5e9      # 1.45 B float32 params
     assert total < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("arch", ["mamba2-1.3b", "granite-4.0-h-micro"])
+def test_prepared_decode_step_reads_its_weights_in_place(one_chip, arch):
+    """With its dot-only weights rounded once (``Model.serving_params``,
+    as the engine prepares them where the float32 dot is one bfloat16
+    pass), the decode step converts and copies no weight: the float32
+    weights' program converts each stack (and slices granite's into runs)
+    in every call, into temporaries as large as the bfloat16 stacks."""
+    cfg = get_config(arch)
+    if cfg.layer_types:
+        cfg = cfg.with_(n_layers=10, layer_types=cfg.layer_types[:10])
+    model = Model(cfg)
+    cache = jax.eval_shape(lambda: model.init_cache(8, 1024,
+                                                    dtype=jnp.float32))
+    tokens = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    got = {}
+    for bf16 in (False, True):
+        params = jax.eval_shape(lambda: model.serving_params(
+            model.init(jax.random.PRNGKey(0), dtype=jnp.float32), bf16))
+        compiled = jax.jit(model.decode_step).lower(
+            *_on(one_chip, (params, tokens, cache))).compile()
+        got[bf16] = (len(re.findall(r"(?:convert|slice)\(%params",
+                                    compiled.as_text())),
+                     compiled.memory_analysis().temp_size_in_bytes)
+    (n_f32, temp_f32), (n_bf16, temp_bf16) = got[False], got[True]
+    assert n_f32 >= 2 and n_bf16 == 0
+    assert temp_bf16 < temp_f32 - 0.5e9
